@@ -29,11 +29,14 @@ compacted wall ratio and ``mean_active_fraction`` records how sparse the
 run actually was (from the ``active_tiles`` telemetry stat, fetched with
 the chunk stats — no extra syncs).
 
-A second axis sweeps *devices*: each ``DEVICE_CONFIGS`` row re-executes
-this script in a subprocess with ``XLA_FLAGS=
---xla_force_host_platform_device_count=N`` (N = 1/2/4 forced CPU
-devices) and runs the 4-chip distributed engine on the resulting
-ExecMesh, once with the synchronous boundary exchange and once
+A second axis sweeps *devices*: on the CPU backend each
+``DEVICE_CONFIGS`` row re-executes this script in a CPU-pinned subprocess
+with ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` (N = 1/2/4
+forced CPU devices — a rehearsal of the mesh); on an accelerator the row
+runs in this process over the devices present (one process holds the
+chip: a child could not open it).  Either way it runs the 4-chip
+distributed engine on the resulting ExecMesh, once with the synchronous
+boundary exchange and once
 double-buffered (``EngineConfig.double_buffer``).  Counters, values and
 the physical trace are asserted identical between the two modes (the
 double-buffer flag itself is excluded — it is priced, not measured);
@@ -241,14 +244,27 @@ def _device_row(app_name: str, tiles: int, scale: int, oq_cap: int,
 def bench_devices(app_name: str, tiles: int, scale: int, oq_cap: int,
                   chunk: int, use_proxy: bool, devices: int,
                   repeats: int = 2) -> dict:
-    """Spawn the forced-device-count worker and collect its row.  The
-    device count must be baked into XLA_FLAGS before jax imports, hence
-    the subprocess re-exec."""
+    """One devices-axis row.  On the CPU backend: spawn the
+    forced-device-count worker (the count must be baked into XLA_FLAGS
+    before jax imports, hence the CPU-pinned re-exec) and collect its
+    row.  On an accelerator: run in-process over the devices present,
+    and refuse any other count."""
+    import jax
     spec = dict(app_name=app_name, tiles=tiles, scale=scale,
                 oq_cap=oq_cap, chunk=chunk, use_proxy=use_proxy,
                 devices=devices, repeats=repeats)
+    if jax.default_backend() != "cpu":
+        if devices != jax.device_count():
+            raise RuntimeError(
+                f"{devices} devices requested but {jax.device_count()} "
+                f"{jax.default_backend()} devices are present; on an "
+                f"accelerator the devices axis runs over the devices "
+                f"present")
+        out = _device_row(**spec)
+        _device_csv(out)
+        return out
     here = os.path.dirname(os.path.abspath(__file__))
-    env = dict(os.environ)
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
     env["XLA_FLAGS"] = (
         env.get("XLA_FLAGS", "")
         + f" --xla_force_host_platform_device_count={devices}").strip()
@@ -265,13 +281,17 @@ def bench_devices(app_name: str, tiles: int, scale: int, oq_cap: int,
             f"{proc.stdout[-1000:]}\n{proc.stderr[-2000:]}")
     lines = [ln for ln in proc.stdout.splitlines() if ln.startswith("ROW ")]
     out = json.loads(lines[-1][4:])
-    row(f"engine_throughput/{app_name}-4chips-{devices}dev"
-        f"{'-proxy' if use_proxy else ''}",
+    _device_csv(out)
+    return out
+
+
+def _device_csv(out: dict) -> None:
+    row(f"engine_throughput/{out['app']}-4chips-{out['devices']}dev"
+        f"{'-proxy' if out['proxy'] else ''}",
         out["wall_s_db"] * 1e6,
         f"db sim win {out['db_sim_win'] * 100:.1f}% "
         f"wall sync/db {out['speedup']:.2f}x "
         f"mesh {out['mesh_devices']}dev")
-    return out
 
 
 # (app, oq_cap, chunk, use_proxy, compaction): the dispatch-bound
@@ -305,6 +325,9 @@ DEVICE_COUNTS = (1, 2, 4)
 
 def run(small: bool = True, out_path: str = DEFAULT_OUT,
         device_counts=DEVICE_COUNTS) -> list:
+    import jax
+    if jax.default_backend() != "cpu" and device_counts:
+        device_counts = (jax.device_count(),)    # the devices present
     rows = []
     for app_name, oq, chunk, px, comp in CONFIGS_1024:
         rows.append(bench_config(app_name, 1024, 11, oq, chunk, px,
@@ -363,6 +386,7 @@ def _write(rows: list, out_path: str) -> None:
 
 
 if __name__ == "__main__":
+    from repro.runtime.compile_cache import enable_compile_cache
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--smoke", action="store_true",
                     help="tiny CI config, asserts bit-identity")
@@ -375,6 +399,7 @@ if __name__ == "__main__":
                     help="output JSON path")
     ap.add_argument("--_worker", default=None, help=argparse.SUPPRESS)
     args = ap.parse_args()
+    enable_compile_cache()
     if args._worker is not None:
         print("ROW " + json.dumps(_device_row(**json.loads(args._worker))))
     elif args.smoke:

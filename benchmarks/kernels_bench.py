@@ -9,7 +9,9 @@ from common import row, timed
 
 import jax.numpy as jnp
 
+from repro.kernels import deliver_fused as df
 from repro.kernels import ops
+from repro.kernels import relax_min as rx
 
 
 def run(small: bool = True):
@@ -18,16 +20,21 @@ def run(small: bool = True):
     bins = 1024
     idx = jnp.asarray(rng.integers(0, bins, n).astype(np.int32))
     _, us = timed(lambda: np.asarray(ops.histogram(idx, bins)))
-    # VMEM working set per grid step: block_r idx + block_b partials
+    # VMEM per grid step: six (8, 128) f32/int32 blocks (record keys and
+    # values, mailbox in and out, counts, accumulator) plus one
+    # (128 records x 128 slots) one-hot hit tile
+    tile = df.ROWS_R * df.LANES * 4
     row("kernels/histogram", us,
-        f"n={n};bins={bins};vmem_block_bytes={1024*4 + 512*4}")
+        f"n={n};bins={bins};vmem_block_bytes={6 * tile};"
+        f"hit_tile_bytes={df.LANES * df.LANES * 4}")
 
     v = jnp.asarray(rng.random(n).astype(np.float32))
     m = jnp.asarray(rng.random(n).astype(np.float32))
     f = jnp.asarray(rng.random(n) < 0.5)
     _, us = timed(lambda: [np.asarray(x) for x in
                            ops.relax(v, m, f, combine="min")])
-    row("kernels/relax_min", us, f"n={n};streams=3x{2048*4}B")
+    row("kernels/relax_min", us,
+        f"n={n};f32_block_bytes={rx.BLOCK_ROWS * rx.LANES * 4}")
 
     seg = jnp.asarray(rng.integers(0, 512, n).astype(np.int32))
     _, us = timed(lambda: np.asarray(

@@ -92,6 +92,8 @@ def main(argv=None) -> None:
     ap.add_argument("--manifest", default=MANIFEST_OUT,
                     help="where to write BENCH_manifest.json")
     args = ap.parse_args(argv)
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.trace:
         export_trace(args.trace, args.report_stem)
         return

@@ -16,7 +16,18 @@ grid programs mapping to the same window are *aliased writes*:
     non-deterministic).  The pass rejects them.
 
 Partially overlapping windows (possible only with element-indexed
-maps / misaligned blocking) are rejected unconditionally.
+maps / misaligned blocking) are rejected unconditionally.  So are a
+window revisited by two separate runs of grid programs (the TPU writes
+an output block back when the block index changes, so a second run
+starts from a stale buffer and overwrites the first run's result) and
+an output window no program writes (it is left uninitialised).
+
+Index maps that read scalar-prefetch tables (work lists, the BCSR
+column table) are evaluated against the tables the call was actually
+given: the capture records them, so a data-driven grid is checked on
+the windows it really visits.  A table that was not concrete at
+capture time (the call sat under a ``jit`` trace) is reported, since
+its windows cannot be evaluated.
 
 Calls are captured by temporarily wrapping ``pallas.pallas_call`` while
 invoking the kernel entry point on tiny inputs (``capture_pallas_calls``)
@@ -30,8 +41,10 @@ from __future__ import annotations
 import contextlib
 import dataclasses
 import itertools
-from typing import Dict, List, Tuple
+from typing import Dict, List, Optional, Tuple
 
+import jax
+import numpy as np
 from jax.experimental import pallas as pl
 
 from .findings import Finding
@@ -48,6 +61,7 @@ class CapturedCall:
     out_specs: List[object]           # normalized to a list of BlockSpec
     out_shapes: List[Tuple[int, ...]]
     n_prefetch: int = 0               # scalar-prefetch args the index maps take
+    prefetch: Optional[Tuple[np.ndarray, ...]] = None   # their values
 
 
 def _kernel_name(kernel) -> str:
@@ -81,13 +95,21 @@ def capture_pallas_calls():
             else [out_specs]
         shapes = out_shape if isinstance(out_shape, (list, tuple)) \
             else [out_shape]
-        captured.append(CapturedCall(
+        call = CapturedCall(
             kernel_name=_kernel_name(kernel),
             grid=tuple(int(g) for g in grid),
             out_specs=specs,
             out_shapes=[tuple(s.shape) for s in shapes],
-            n_prefetch=n_prefetch))
-        return real(kernel, **kw)
+            n_prefetch=n_prefetch)
+        captured.append(call)
+        run = real(kernel, **kw)
+
+        def invoke(*args):
+            tables = args[:n_prefetch]
+            if not any(isinstance(t, jax.core.Tracer) for t in tables):
+                call.prefetch = tuple(np.asarray(t) for t in tables)
+            return run(*args)
+        return invoke
 
     pl.pallas_call = wrapper
     try:
@@ -96,33 +118,32 @@ def capture_pallas_calls():
         pl.pallas_call = real
 
 
-class _PrefetchStub:
-    """Stands in for a scalar-prefetch ref in index-map evaluation: block
-    indices derived from prefetched tables (e.g. the BCSR column table)
-    resolve to 0 — which window they select doesn't affect *aliasing*
-    between (program, window) pairs driven by the grid coordinates."""
-
-    def __getitem__(self, _):
-        return 0
-
-
-def _program_windows(call: CapturedCall, spec) -> Dict[Tuple, List[Tuple]]:
-    """window -> list of grid programs writing it.  A window is a tuple
-    of per-dim (start, stop) element ranges: ``index_map`` returns block
+def _program_windows(call: CapturedCall, spec) -> Dict[Tuple, List[int]]:
+    """window -> grid programs writing it, as positions in grid order
+    (row-major: the order the TPU runs them).  A window is a tuple of
+    per-dim (start, stop) element ranges: ``index_map`` returns block
     indices, scaled by ``block_shape`` (the installed Pallas convention —
-    see e.g. ``kernels/histogram_bin.py``)."""
+    see e.g. ``kernels/relax_min.py``)."""
     block = tuple(int(b) for b in spec.block_shape)
     ranges = [range(max(int(g), 1)) for g in call.grid] or [range(1)]
-    stubs = tuple(_PrefetchStub() for _ in range(call.n_prefetch))
-    windows: Dict[Tuple, List[Tuple]] = {}
-    for program in itertools.product(*ranges):
-        idx = spec.index_map(*program, *stubs)
+    tables = call.prefetch or ()
+    windows: Dict[Tuple, List[int]] = {}
+    for pos, program in enumerate(itertools.product(*ranges)):
+        idx = spec.index_map(*program, *tables)
         if not isinstance(idx, tuple):
             idx = (idx,)
         win = tuple((int(i) * b, (int(i) + 1) * b)
                     for i, b in zip(idx, block))
-        windows.setdefault(win, []).append(program)
+        windows.setdefault(win, []).append(pos)
     return windows
+
+
+def _all_windows(shape: Tuple[int, ...], spec) -> set:
+    """Every block window tiling an output of ``shape``."""
+    block = tuple(int(b) for b in spec.block_shape)
+    counts = [-(-int(n) // b) for n, b in zip(shape, block)]
+    return {tuple((i * b, (i + 1) * b) for i, b in zip(idx, block))
+            for idx in itertools.product(*(range(c) for c in counts))}
 
 
 def _windows_overlap(a: Tuple, b: Tuple) -> bool:
@@ -136,9 +157,33 @@ def check_call(call: CapturedCall, combine: str, where: str) -> List[Finding]:
     canonically ``'overwrite'`` — order-sensitive)."""
     findings = []
     commutative = combine in COMMUTATIVE
-    for out_i, spec in enumerate(call.out_specs):
+    site = f"{where}:{call.kernel_name}"
+    if call.n_prefetch and call.prefetch is None:
+        return [Finding(
+            "pallas_races", "prefetch-unknown", site,
+            f"index maps read {call.n_prefetch} scalar-prefetch table(s) "
+            f"that were not concrete at capture (traced under jit?): the "
+            f"output windows cannot be evaluated")]
+    for out_i, (spec, shape) in enumerate(zip(call.out_specs,
+                                              call.out_shapes)):
         windows = _program_windows(call, spec)
         site = f"{where}:{call.kernel_name}[out{out_i}]"
+        unwritten = sorted(_all_windows(shape, spec) - set(windows))
+        if unwritten:
+            findings.append(Finding(
+                "pallas_races", "unwritten-window", site,
+                f"{len(unwritten)} output window(s) written by no grid "
+                f"program (e.g. {unwritten[0]}): left uninitialised"))
+        split = {w: ps for w, ps in windows.items()
+                 if ps[-1] - ps[0] + 1 != len(ps)}
+        if split:
+            w, ps = next(iter(sorted(split.items())))
+            findings.append(Finding(
+                "pallas_races", "split-revisit", site,
+                f"{len(split)} output window(s) revisited by separate runs "
+                f"of grid programs (e.g. window {w} at grid positions "
+                f"{ps[:6]}): a later run overwrites the earlier one's "
+                f"written-back block"))
         # aliased writes: >1 program revisits one window
         aliased = {w: ps for w, ps in windows.items() if len(ps) > 1}
         if aliased and not commutative:
@@ -146,7 +191,7 @@ def check_call(call: CapturedCall, combine: str, where: str) -> List[Finding]:
             findings.append(Finding(
                 "pallas_races", "aliased-overwrite", site,
                 f"{len(aliased)} output window(s) written by multiple grid "
-                f"programs (e.g. window {w} by programs {ps[:4]}) with "
+                f"programs (e.g. window {w} at grid positions {ps[:4]}) with "
                 f"non-commutative combine '{combine}': last program in "
                 f"grid order wins silently"))
         # partial overlap between distinct windows: always wrong
@@ -156,8 +201,8 @@ def check_call(call: CapturedCall, combine: str, where: str) -> List[Finding]:
                 if _windows_overlap(w1, w2):
                     findings.append(Finding(
                         "pallas_races", "window-overlap", site,
-                        f"output windows {w1} (programs "
-                        f"{windows[w1][:2]}) and {w2} (programs "
+                        f"output windows {w1} (grid positions "
+                        f"{windows[w1][:2]}) and {w2} (grid positions "
                         f"{windows[w2][:2]}) partially overlap: "
                         f"misaligned blocking races regardless of the "
                         f"combine"))

@@ -98,14 +98,16 @@ def _lint_cell(name, backend, chips, g, grid, root, bins, hv,
     if chips:
         chunk_fn = eng._get_chunk_fn(_CHUNK_LEN)
         raw = eng._raw_vmap_step()
-        step_one = functools.partial(raw, eng._row_lo_s, eng._row_hi_s)
 
         def step(st, fl):
-            return step_one(st, eng._chip_ids, fl)
+            return raw(eng._graph_s, st, eng._chip_ids, fl)
+        args = _chunk_args(eng, state)
     else:
+        # the graph is an argument of the traced program, as on device
         chunk_fn = functools.partial(eng._chunk_impl, length=_CHUNK_LEN)
         step = eng._chunk_step_one
-    closed = jax.make_jaxpr(chunk_fn)(*_chunk_args(eng, state))
+        args = (eng.graph,) + _chunk_args(eng, state)
+    closed = jax.make_jaxpr(chunk_fn)(*args)
     findings = jaxprlint.lint_jaxpr(closed, where)
     if compaction:
         kernel = eng.kernel if chips else eng

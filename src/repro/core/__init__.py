@@ -1,7 +1,3 @@
 # The paper's primary contribution — implement the SYSTEM here
 # (scheduler, optimizer, data path, serving loop, etc.) in the
 # host framework. Add sibling subpackages for substrates.
-
-# Importing any core submodule installs the jax version-compat shims
-# (jax.shard_map on 0.4.x installs, check_vma -> check_rep translation).
-from . import compat  # noqa: F401

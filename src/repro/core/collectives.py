@@ -36,8 +36,6 @@ import numpy as np
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from .compat import axis_size, shard_map
-
 
 # --------------------------------------------------------------------------
 # hierarchical (proxy) psum — building block, usable INSIDE shard_map
@@ -54,7 +52,7 @@ def proxy_psum(x, region_axis: str, cross_axis: str | None):
     """
     if cross_axis is None:
         return jax.lax.psum(x, region_axis)
-    region = axis_size(region_axis)
+    region = jax.lax.axis_size(region_axis)
     if x.ndim == 0 or x.shape[0] % region != 0:
         return jax.lax.psum(x, (region_axis, cross_axis))
     # 1. regional combine: each region member ends up owning 1/region of
@@ -87,8 +85,8 @@ def hierarchical_psum(x, mesh: Mesh, region_axis: str = "data",
     def f(xl):
         return proxy_psum(xl[0], region_axis, cross_axis)
 
-    return jax.jit(shard_map(f, mesh=mesh, in_specs=(spec,), out_specs=P(),
-                             check_vma=False))(x)
+    return jax.jit(jax.shard_map(f, mesh=mesh, in_specs=(spec,),
+                                 out_specs=P(), check_vma=False))(x)
 
 
 # --------------------------------------------------------------------------
@@ -193,7 +191,7 @@ def compressed_proxy_psum(x, region_axis: str, cross_axis: str | None,
     """
     if cross_axis is None:
         return jax.lax.psum(x, region_axis)
-    region = axis_size(region_axis)
+    region = jax.lax.axis_size(region_axis)
     if x.ndim == 0 or x.shape[0] % region != 0:
         return jax.lax.psum(x, (region_axis, cross_axis))
     shard = jax.lax.psum_scatter(x, region_axis, scatter_dimension=0,

@@ -70,7 +70,7 @@ order, exactly as the per-step loop appended them.
 Hot-spot kernels: with ``EngineConfig.backend="pallas"`` the engine's
 combine/drain hot spots — the IQ-drain relax, the P$ / cascade segment
 min/add, and the owner-mailbox delivery — run through the Pallas kernels
-in ``kernels/`` (``relax_min``, ``segment_combine``, ``histogram_bin``);
+in ``kernels/`` (``relax_min``, ``segment_combine``, ``deliver_fused``);
 the default ``"jnp"`` path is the numerical oracle the Pallas path is
 tested against (bitwise for min-combine apps, up to f32 re-association
 for add).
@@ -97,6 +97,8 @@ from .proxy import (ProxyConfig, cascade_proxy_tile, make_pcache,
 from .tilegrid import ChipPartition, TileGrid
 
 INF = jnp.float32(jnp.inf)
+# edge values that read the per-edge weight array
+_WEIGHTED_EDGE_VALUES = ("add_w", "mul_w")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -247,23 +249,32 @@ class DataLocalEngine:
                                      or app.cascade_profitable):
                 self._cascade_levels = casc.levels
         self._ladder = capacity_ladder(T, cfg.compaction)
-        # per-source arrays padded to the *global* length; in chip mode the
-        # driver partitions these into per-window slices before stepping.
-        self.row_lo = jnp.asarray(_pad(row_lo, self.Ngs, 0), jnp.int32)
-        self.row_hi = jnp.asarray(_pad(row_hi, self.Ngs, 0), jnp.int32)
-        self.col_idx = jnp.asarray(col_idx, jnp.int32)
-        if weights is None:
-            weights = np.ones_like(col_idx, dtype=np.float32)
-        self.weights = jnp.asarray(weights, jnp.float32)
+        # The device-resident graph, passed to every jitted program as an
+        # *argument* (a closed-over array would be embedded in the
+        # program as a constant: compile time and program size would
+        # grow with nnz).  Per-source arrays are padded to the *global*
+        # length; in chip mode the driver partitions row_lo/row_hi into
+        # per-window slices before stepping.  Weights are uploaded only
+        # for apps whose edge value reads them.
+        self.graph = dict(
+            row_lo=jnp.asarray(_pad(row_lo, self.Ngs, 0), jnp.int32),
+            row_hi=jnp.asarray(_pad(row_hi, self.Ngs, 0), jnp.int32),
+            col_idx=jnp.asarray(col_idx, jnp.int32))
+        if app.edge_value in _WEIGHTED_EDGE_VALUES:
+            if weights is None:
+                weights = np.ones_like(col_idx, dtype=np.float32)
+            self.graph["weights"] = jnp.asarray(weights, jnp.float32)
         self._superstep = jax.jit(self._superstep_impl)
         self._chunk = jax.jit(self._chunk_impl, static_argnames=("length",))
         self._stat_names = None        # packed-stat layout, cached per engine
         self._n_seeds = 0              # set by init_state, read by sanitizer
 
-    def chip_superstep(self, row_lo, row_hi, state, chip_id, flush,
+    def chip_superstep(self, graph, state, chip_id, flush,
                        active=None, window=None, pad_off_to=None):
         """One superstep of window ``chip_id``: pure in its array args so
         the distributed driver can vmap / shard_map it across chips.
+        ``graph`` is :attr:`graph` with ``row_lo``/``row_hi`` sliced to
+        the window.
         Returns (new_state, stats, off) where ``off`` is the dict of
         off-chip records (dst, val, mask) to exchange — ``None`` for a
         monolithic window.
@@ -276,7 +287,7 @@ class DataLocalEngine:
         sentinels to the dense length so every compaction bucket of a
         ``lax.switch`` returns identical shapes (and the double-buffer
         bank size is unchanged)."""
-        return self._step(row_lo, row_hi, state, chip_id, flush,
+        return self._step(graph, state, chip_id, flush,
                           active=active, window=window,
                           pad_off_to=pad_off_to)
 
@@ -322,17 +333,17 @@ class DataLocalEngine:
         item starts with its full edge range and a carried value."""
         self._require_mono("activate_all")
         state = dict(state)
-        state["cur_lo"] = self.row_lo
-        state["cur_hi"] = self.row_hi
+        state["cur_lo"] = self.graph["row_lo"]
+        state["cur_hi"] = self.graph["row_hi"]
         state["cur_val"] = jnp.asarray(_pad(cur_val, self.Ns, 0.0), jnp.float32)
         return state
 
     # ------------------------------------------------------------ superstep
-    def _superstep_impl(self, state, flush: jnp.ndarray):
+    def _superstep_impl(self, graph, state, flush: jnp.ndarray):
         """Monolithic superstep: the whole grid as one window."""
-        return self._step_mono(state, flush)
+        return self._step_mono(graph, state, flush)
 
-    def _step_mono(self, state, flush):
+    def _step_mono(self, graph, state, flush):
         """One monolithic superstep, dispatched through the compaction
         ladder: with ``compaction=0`` this is exactly the dense
         ``_step``; otherwise the active-tile count (computed on device
@@ -342,8 +353,8 @@ class DataLocalEngine:
         stats are pure telemetry outputs the fixed-key counter/trace
         accumulators ignore."""
         if len(self._ladder) <= 1:
-            new_state, stats, _ = self._step(self.row_lo, self.row_hi,
-                                             state, jnp.int32(0), flush)
+            new_state, stats, _ = self._step(graph, state, jnp.int32(0),
+                                             flush)
             return new_state, stats
         active = self._active_tiles(state)
         n_act = jnp.sum(active.astype(jnp.int32))
@@ -351,8 +362,8 @@ class DataLocalEngine:
 
         def branch(w):
             def run(st, fl, act):
-                return self._step(self.row_lo, self.row_hi, st,
-                                  jnp.int32(0), fl, active=act, window=w)
+                return self._step(graph, st, jnp.int32(0), fl, active=act,
+                                  window=w)
             return run
 
         new_state, stats, _ = jax.lax.switch(
@@ -375,23 +386,23 @@ class DataLocalEngine:
                       .reshape(T, self.Cs), axis=1)
         return mail | cur
 
-    def _edge_value(self, cval, pos):
+    def _edge_value(self, graph, cval, pos):
         """Per-edge record value from the source cursor value and the
         edge position (shared by the dense and compacted emit fronts)."""
         app = self.app
         if app.edge_value == "add_w":
-            return cval + self.weights[pos]
+            return cval + graph["weights"][pos]
         if app.edge_value == "add_one":
             return cval + 1.0
         if app.edge_value == "mul_w":
-            return cval * self.weights[pos]
+            return cval * graph["weights"][pos]
         if app.edge_value == "carry":
             return cval
         if app.edge_value == "one":
             return jnp.ones_like(cval)
         raise ValueError(app.edge_value)
 
-    def _front_dense(self, row_lo, row_hi, state, tile_gids):
+    def _front_dense(self, graph, state, tile_gids):
         """Dense IQ drain + OQ emit over all T tiles (the oracle path).
 
         Returns (new_vals, mail_val, mail_flag, cur_lo, cur_hi, cur_val,
@@ -433,8 +444,8 @@ class DataLocalEngine:
             # engine's rendering of data staleness: measurable wasted work).
             re = improved[: self.Ns] if self.Nd == self.Ns else jnp.zeros(
                 (self.Ns,), jnp.bool_)
-            cur_lo = jnp.where(re, row_lo, cur_lo)
-            cur_hi = jnp.where(re, row_hi, cur_hi)
+            cur_lo = jnp.where(re, graph["row_lo"], cur_lo)
+            cur_hi = jnp.where(re, graph["row_hi"], cur_hi)
             cur_val = jnp.where(re, new_vals[: self.Ns], cur_val)
 
         # ---- 2. OQ emit (budgeted edge streaming) -------------------------
@@ -455,9 +466,10 @@ class DataLocalEngine:
         vglob = vslot + jnp.arange(T, dtype=jnp.int32)[:, None] * Cs
         pos = cur_lo[vglob] + offset
         emit_mask = b_idx[None, :] < total_take[:, None]
-        pos = jnp.clip(pos, 0, self.col_idx.shape[0] - 1)
-        dst = self.col_idx[pos]
-        cand = self._edge_value(cur_val[vglob], pos)
+        col_idx = graph["col_idx"]
+        pos = jnp.clip(pos, 0, col_idx.shape[0] - 1)
+        dst = col_idx[pos]
+        cand = self._edge_value(graph, cur_val[vglob], pos)
         cur_lo = cur_lo + (take_v2d.reshape(-1))
 
         # flatten records (tile ids are global; dst indices are global)
@@ -470,7 +482,7 @@ class DataLocalEngine:
                 consumed_per_tile, total_take, consumed_per_tile,
                 total_take, dst, cand, emit_mask, src_tile)
 
-    def _front_compact(self, row_lo, row_hi, state, chip_id, active, W):
+    def _front_compact(self, graph, state, chip_id, active, W):
         """Compacted IQ drain + OQ emit over a W-tile active window.
 
         Active tiles are compacted (stably, preserving tile order) into
@@ -526,8 +538,8 @@ class DataLocalEngine:
         if react:
             # Cd == Cs here, so ``improvedW`` is laid out exactly like
             # the windowed cursor rows (the dense path's improved[:Ns])
-            row_loW = row_lo.reshape(T, Cs)[w_rows].reshape(-1)
-            row_hiW = row_hi.reshape(T, Cs)[w_rows].reshape(-1)
+            row_loW = graph["row_lo"].reshape(T, Cs)[w_rows].reshape(-1)
+            row_hiW = graph["row_hi"].reshape(T, Cs)[w_rows].reshape(-1)
             cur_loW = jnp.where(improvedW, row_loW, cur_loW)
             cur_hiW = jnp.where(improvedW, row_hiW, cur_hiW)
             cur_valW = jnp.where(improvedW, nvW, cur_valW)
@@ -551,9 +563,10 @@ class DataLocalEngine:
         vglob = vslot + jnp.arange(W, dtype=jnp.int32)[:, None] * Cs
         pos = cur_loW[vglob] + offset
         emit_mask = b_idx[None, :] < total_take[:, None]
-        pos = jnp.clip(pos, 0, self.col_idx.shape[0] - 1)
-        dst = self.col_idx[pos]
-        cand = self._edge_value(cur_valW[vglob], pos)
+        col_idx = graph["col_idx"]
+        pos = jnp.clip(pos, 0, col_idx.shape[0] - 1)
+        dst = col_idx[pos]
+        cand = self._edge_value(graph, cur_valW[vglob], pos)
         cur_loW = cur_loW + (take_v2d.reshape(-1))
 
         # ---- ONE fused (W, .) scatter-back for the whole state ------------
@@ -607,7 +620,7 @@ class DataLocalEngine:
                 consumedW, total_take, consumed_full, edges_full, dst,
                 cand, emit_mask, src_tile)
 
-    def _step(self, row_lo, row_hi, state, chip_id, flush, active=None,
+    def _step(self, graph, state, chip_id, flush, active=None,
               window=None, pad_off_to=None):
         app, cfg, grid = self.app, self.cfg, self.cfg.grid
         T, Cs, Cd = self.T, self.Cs, self.Cd
@@ -620,14 +633,14 @@ class DataLocalEngine:
             (new_vals, mail_val, mail_flag, cur_lo, cur_hi, cur_val,
              consumed_vec, edges_vec, consumed_per_tile, edges_per_tile,
              dst, cand, emit_mask, src_tile) = self._front_dense(
-                row_lo, row_hi, state, tile_gids)
+                graph, state, tile_gids)
         else:
             if active is None:
                 active = self._active_tiles(state)
             (new_vals, mail_val, mail_flag, cur_lo, cur_hi, cur_val,
              consumed_vec, edges_vec, consumed_per_tile, edges_per_tile,
              dst, cand, emit_mask, src_tile) = self._front_compact(
-                row_lo, row_hi, state, chip_id, active, window)
+                graph, state, chip_id, active, window)
         vals = state["values"]
         owner = jnp.minimum(dst // Cd, self.Tg - 1)
 
@@ -784,7 +797,8 @@ class DataLocalEngine:
         key = jnp.where(emit_mask, ptile_l * S + slot, T * S)  # sentinel at end
         dkey = jnp.where(emit_mask, dst, self.Ngd)
         (skey, sdst, smask, (scand,),
-         new_slot, new_dst, gid) = _lex_group(key, dkey, emit_mask, cand)
+         new_slot, new_dst, gid) = _lex_group(key, dkey, T * S, cand,
+                                              stable=not is_min)
         gagg = self._segment_reduce(scand, smask, gid, is_min)
         combined = gagg[gid]                                   # per-record view
         n_leaders = jnp.sum(new_dst)
@@ -1096,7 +1110,8 @@ class DataLocalEngine:
         tkey = jnp.where(alive, ptile_l, T)
         dkey = jnp.where(alive, dst, self.Ngd)
         (stile, sdst, salive, (sval,),
-         _, leader, gid) = _lex_group(tkey, dkey, alive, val)
+         _, leader, gid) = _lex_group(tkey, dkey, T, val,
+                                      stable=not is_min)
         agg = self._segment_reduce(sval, salive, gid, is_min)
         nval = agg[gid]
         merged = (jnp.sum(salive) - jnp.sum(leader)).astype(jnp.float32)
@@ -1112,8 +1127,11 @@ class DataLocalEngine:
         R = gid.shape[0]
         if self.cfg.backend == "pallas":
             from ..kernels import ops as kops
+            # gid ascends over the live records, which precede the masked
+            # ones (sentinel keys sort last): already in kernel order
             return kops.segment_combine(jnp.where(smask, gid, -1), sval, R,
-                                        combine="min" if is_min else "add")
+                                        combine="min" if is_min else "add",
+                                        presorted=True)
         if is_min:
             return jax.ops.segment_min(jnp.where(smask, sval, INF), gid,
                                        num_segments=R,
@@ -1123,17 +1141,20 @@ class DataLocalEngine:
 
     # ------------------------------------------------------- chunked stepping
     def _chunk_step_one(self, st, fl):
-        """One monolithic superstep as a (state, stats) pair — the scan
-        body unit of the chunked run loop (compaction-ladder dispatched,
-        like the per-step path)."""
-        return self._step_mono(st, fl)
+        """One monolithic superstep on this engine's graph as a (state,
+        stats) pair — the scan body unit of the chunked run loop
+        (compaction-ladder dispatched, like the per-step path), for
+        abstract traces."""
+        return self._step_mono(self.graph, st, fl)
 
-    def _chunk_impl(self, state, flush, done, steps_left, *, length: int):
+    def _chunk_impl(self, graph, state, flush, done, steps_left, *,
+                    length: int):
         """Scan ``length`` monolithic supersteps in one device dispatch
         (see :func:`_scan_steps` for the carry/termination contract)."""
         write_back = self.cfg.proxy is not None and self.cfg.proxy.write_back
-        return _scan_steps(self._chunk_step_one, state, flush, done,
-                           steps_left, length, write_back)
+        return _scan_steps(
+            lambda st, fl: self._step_mono(graph, st, fl), state, flush,
+            done, steps_left, length, write_back)
 
     # ----------------------------------------------------------------- run
     def run(self, state, max_supersteps: Optional[int] = None,
@@ -1214,7 +1235,7 @@ class DataLocalEngine:
                         cycles += s + fill
                 return cycles
 
-            chunk_fn = functools.partial(self._chunk, length=K)
+            chunk_fn = functools.partial(self._chunk, self.graph, length=K)
             state, steps, cycles = _drain_chunked(
                 chunk_fn, state, maxs, self._stat_names, counters, trace,
                 cfg.element_bits, progress, add_chunk_cycles, cycles,
@@ -1252,7 +1273,7 @@ class DataLocalEngine:
         flush_flag = jnp.asarray(False)
         while steps < maxs:
             t0 = time.perf_counter()
-            state, stats = self._superstep(state, flush_flag)
+            state, stats = self._superstep(self.graph, state, flush_flag)
             t1 = time.perf_counter()
             stats = jax.device_get(stats)
             sync_ctr.inc()
@@ -1590,18 +1611,21 @@ def _scan_steps(step_one, state, flush, done, steps_left, length: int,
                         length=length)
 
 
-def _lex_group(key, sub, mask, *vals):
+def _lex_group(key, sub, key_end, *vals, stable: bool = True):
     """Single-sort lexicographic (key, sub) record grouping.
 
-    One fused stable ``lax.sort`` with ``num_keys=2`` orders records by
-    the (key, sub) composite — the sort the two-stable-argsort idiom
+    One fused ``lax.sort`` with ``num_keys=2`` orders records by the
+    (key, sub) composite — the sort the two-stable-argsort idiom
     (argsort by sub, then by key) and a packed ``(key << k) | sub``
     key both express, but with one sort pass, no gathers, and no int64
-    requirement — carrying ``mask`` and ``vals`` along as passengers.
-    Masked records must hold sentinel keys that order after all live
-    ones.  Ties in (key, sub) keep arrival order (stability), so
-    downstream f32 segment sums accumulate in the same order as the
-    two-argsort formulation: bit-identical results.
+    requirement — carrying ``vals`` along as passengers.  Masked records
+    must hold the sentinel key ``key_end``, above every live key, so
+    they sort last and the live mask is recovered from the sorted keys.
+    With ``stable`` ties in (key, sub) keep arrival order, so downstream
+    f32 segment sums accumulate in the same order as the two-argsort
+    formulation: bit-identical results.  Min combines are
+    order-independent and pass ``stable=False``: the TPU compiles an
+    unstable sort about twice as fast.
 
     Returns (skey, ssub, smask, svals, new_key, new_pair, gid):
       new_key:  sorted-order mask of the first live record of each key;
@@ -1609,8 +1633,9 @@ def _lex_group(key, sub, mask, *vals):
                 leaders; gid numbers the groups (masked rows -> last id).
     """
     R = key.shape[0]
-    skey, ssub, smask, *svals = jax.lax.sort(
-        (key, sub, mask) + tuple(vals), num_keys=2, is_stable=True)
+    skey, ssub, *svals = jax.lax.sort(
+        (key, sub) + tuple(vals), num_keys=2, is_stable=stable)
+    smask = skey < key_end
     first = jnp.arange(R) == 0
     new_key = smask & (first | (skey != jnp.roll(skey, 1)))
     new_pair = smask & (new_key | (ssub != jnp.roll(ssub, 1)))
@@ -1729,15 +1754,16 @@ def _deliver(mail_val, mail_flag, dst, val, mask, owner, T, Nd, is_min,
 
 def _deliver_pallas(mail_val, mail_flag, dst, val, mask, owner, T, Nd,
                     is_min):
-    """Pallas rendering of the owner delivery, fused into ONE launch
-    (``kernels.deliver_fused``): the kernel reads the record stream once
-    and produces both the relaxed mailbox (min: guarded running minimum
+    """Pallas rendering of the owner delivery in one launch
+    (``kernels.deliver_fused``): the records are sorted by destination,
+    and a work list of (mailbox block, record block) pairs drives a grid
+    over (8, 128) blocks, so each mailbox block folds in only the record
+    blocks that hold its records.  It returns the relaxed mailbox (min:
     == scatter-min, bitwise; add: accumulate — equal to the jnp oracle
     up to f32 re-association) and the per-index arrival counts.  Flags
     and per-tile endpoint contention derive from the counts exactly like
     the jnp path (counts are integers, mailbox indices of one tile are
-    contiguous) — this replaces the former four-launch chain
-    (segment_combine + 2x histogram + relax)."""
+    contiguous)."""
     from ..kernels import ops as kops
     comb = "min" if is_min else "add"
     seg = jnp.where(mask, dst, -1)                 # negative = padding

@@ -84,6 +84,11 @@ from ..obs.timeline import RunMeta
 from .mesh import ExecMesh
 
 
+# graph arrays partitioned per chip window (the edge arrays stay whole:
+# a window's cursors index the global edge list)
+_WINDOWED = ("row_lo", "row_hi")
+
+
 def partition(grid: TileGrid, num_chips: int) -> ChipPartition:
     """Partition ``grid`` into the most square chip grid that divides it."""
     return partition_grid(grid, num_chips)
@@ -374,7 +379,7 @@ class _FaultTolerance:
         _, new_ndev = eng._drop_device()
         # 4. restore the carry through the elastic reshard path: chip-
         #    stacked leaves re-shard over the surviving device axis
-        jmesh = jax.make_mesh((eng.mesh.ndev,), (eng.mesh.axis,))
+        jmesh = eng.mesh.jax_mesh()
 
         def rule(path, shape):
             if shape and shape[0] == eng.C and eng.mesh.is_sharded:
@@ -442,8 +447,16 @@ class DistributedEngine:
         inv = np.empty_like(perm)
         inv[perm] = np.arange(perm.shape[0])
         self._inv = inv
-        self._row_lo_s = self._shard(np.asarray(self.kernel.row_lo), self.Cs)
-        self._row_hi_s = self._shard(np.asarray(self.kernel.row_hi), self.Cs)
+        # the graph argument of every step program: per-chip row ranges
+        # stacked along the chips axis, the edge arrays whole (replicated
+        # on every device)
+        self._graph_s = dict(self.kernel.graph)
+        for k in _WINDOWED:
+            self._graph_s[k] = self._shard(np.asarray(self.kernel.graph[k]),
+                                           self.Cs)
+        self._graph_sharded = {k: k in _WINDOWED for k in self._graph_s}
+        self._graph_axes = {k: 0 if k in _WINDOWED else None
+                            for k in self._graph_s}
         self._chip_ids = jnp.arange(self.C, dtype=jnp.int32)
         # device placement: any ndev dividing C works; when the host's
         # device count doesn't divide, ExecMesh falls back to the largest
@@ -525,8 +538,8 @@ class DistributedEngine:
 
     def activate_all(self, state, cur_val):
         state = dict(state)
-        state["cur_lo"] = self._row_lo_s
-        state["cur_hi"] = self._row_hi_s
+        state["cur_lo"] = self._graph_s["row_lo"]
+        state["cur_hi"] = self._graph_s["row_hi"]
         state["cur_val"] = self._shard(
             _pad(np.asarray(cur_val, np.float32), self.kernel.Ngs, 0.0),
             self.Cs)
@@ -540,13 +553,14 @@ class DistributedEngine:
             mesh = self.mesh
             step = self._raw_step(mesh)
 
-            def fn(row_lo, row_hi, state, flush):
-                return step(row_lo, row_hi, state, mesh.chip_ids(), flush)
+            def fn(graph, state, flush):
+                return step(graph, state, mesh.chip_ids(), flush)
 
-            jstep = mesh.shard_jit(fn, in_specs=(True, True, True, False),
-                                   out_specs=(True, False))
-            self._step = lambda state, flush: jstep(
-                self._row_lo_s, self._row_hi_s, state, flush)
+            jstep = mesh.shard_jit(
+                fn, in_specs=(self._graph_sharded, True, False),
+                out_specs=(True, False))
+            self._step = lambda state, flush: jstep(self._graph_s, state,
+                                                    flush)
         return self._step
 
     def _get_chunk_fn(self, length: int):
@@ -593,7 +607,9 @@ class DistributedEngine:
         pad_off = (self._off_record_len()
                    if multi and len(ladder) > 1 else None)
 
-        def step(row_lo, row_hi, state, chip_ids, flush):
+        graph_axes = self._graph_axes
+
+        def step(graph, state, chip_ids, flush):
             if double_buffer:
                 # previous superstep's deferred exchange lands first —
                 # the same scatter, one superstep later (the mailbox is
@@ -615,8 +631,8 @@ class DistributedEngine:
                         return jax.vmap(
                             functools.partial(kernel.chip_superstep,
                                               window=w, pad_off_to=pad_off),
-                            in_axes=(0, 0, 0, 0, None, 0))(
-                            row_lo, row_hi, st, chip_ids, flush, act)
+                            in_axes=(graph_axes, 0, 0, None, 0))(
+                            graph, st, chip_ids, flush, act)
                     return run
 
                 new_state, stats, off = jax.lax.switch(
@@ -628,8 +644,9 @@ class DistributedEngine:
                         jnp.asarray(ladder, jnp.float32), idx)))
             else:
                 new_state, stats, off = jax.vmap(
-                    kernel.chip_superstep, in_axes=(0, 0, 0, 0, None))(
-                    row_lo, row_hi, state, chip_ids, flush)
+                    kernel.chip_superstep,
+                    in_axes=(graph_axes, 0, 0, None))(
+                    graph, state, chip_ids, flush)
             if multi:
                 # board-level exchange: every chip gathers the full
                 # off-chip record stream and keeps what it owns
@@ -696,8 +713,8 @@ class DistributedEngine:
                                                    jnp.float32)
             off = jax.eval_shape(
                 lambda s: jax.vmap(k.chip_superstep,
-                                   in_axes=(0, 0, 0, 0, None))(
-                    self._row_lo_s, self._row_hi_s, s, self._chip_ids,
+                                   in_axes=(self._graph_axes, 0, 0, None))(
+                    self._graph_s, s, self._chip_ids,
                     jnp.zeros((), jnp.bool_))[2],
                 st)
             self._off_len = int(off["dst"].shape[1])
@@ -713,7 +730,7 @@ class DistributedEngine:
         # every device at any ndev)
         bank_len = self.C * self._off_record_len() if db else 0
 
-        def fn(row_lo, row_hi, state, flush, done, left):
+        def fn(graph, state, flush, done, left):
             # the scan lives *inside* the sharded region: state stays
             # device-sharded across the whole chunk and each iteration's
             # collective exchange/psum executes on device — the host only
@@ -728,7 +745,7 @@ class DistributedEngine:
                              _db_val=jnp.zeros((bank_len,), jnp.float32),
                              _db_mask=jnp.zeros((bank_len,), bool))
             carry, out = _scan_steps(
-                lambda st, fl: step(row_lo, row_hi, st, chip_ids, fl),
+                lambda st, fl: step(graph, st, chip_ids, fl),
                 state, flush, done, left, length, write_back)
             if db:
                 st, fl2, dn, lf = carry
@@ -736,10 +753,10 @@ class DistributedEngine:
             return carry, out
 
         jfn = mesh.shard_jit(
-            fn, in_specs=(True, True, True, False, False, False),
+            fn, in_specs=(self._graph_sharded, True, False, False, False),
             out_specs=((True, False, False, False), False))
         return lambda state, flush, done, left: jfn(
-            self._row_lo_s, self._row_hi_s, state, flush, done, left)
+            self._graph_s, state, flush, done, left)
 
     # ------------------------------------------------------------------ run
     def run(self, state, max_supersteps: Optional[int] = None,
@@ -890,8 +907,8 @@ class DistributedEngine:
             if self._stat_names is None:   # one abstract trace per engine
                 raw = self._raw_vmap_step()
                 self._stat_names = _stat_keys(
-                    lambda st, fl: raw(self._row_lo_s, self._row_hi_s, st,
-                                       self._chip_ids, fl),
+                    lambda st, fl: raw(self._graph_s, st, self._chip_ids,
+                                       fl),
                     state, jnp.zeros((), jnp.bool_))
             def add_chunk_cycles(stacked, n_act, cycles):
                 # monolithic BSP terms maxed with the board leg, plus

@@ -35,7 +35,6 @@ import jax
 import jax.numpy as jnp
 
 from ..core import collectives
-from ..core.compat import shard_map
 
 
 def largest_dividing_devices(num_chips: int, device_count: int) -> int:
@@ -152,6 +151,13 @@ class ExecMesh:
         def conv(tree):
             return jax.tree.map(lambda b: P(self.axis) if b else P(), tree)
 
-        mesh = jax.make_mesh((self.ndev,), (self.axis,))
-        return jax.jit(shard_map(fn, mesh=mesh, in_specs=conv(in_specs),
-                                 out_specs=conv(out_specs), check_vma=False))
+        return jax.jit(jax.shard_map(fn, mesh=self.jax_mesh(),
+                                     in_specs=conv(in_specs),
+                                     out_specs=conv(out_specs),
+                                     check_vma=False))
+
+    def jax_mesh(self):
+        """The ``jax.sharding.Mesh`` of this placement (the first ``ndev``
+        devices, one ``Auto``-typed axis)."""
+        return jax.make_mesh((self.ndev,), (self.axis,),
+                             axis_types=(jax.sharding.AxisType.Auto,))

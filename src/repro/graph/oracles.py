@@ -10,20 +10,23 @@ from .csr import CSR
 
 
 def bfs_oracle(g: CSR, root: int) -> np.ndarray:
+    """Hop levels from ``root`` (inf where unreached), level-synchronous:
+    each level gathers the frontier's whole edge range at once, so a
+    Graph500-scale graph takes seconds, not a Python loop per edge."""
     n = g.n_rows
     dist = np.full(n, np.inf, np.float32)
     dist[root] = 0
-    frontier = [root]
+    frontier = np.array([root], np.int64)
     d = 0
-    while frontier:
-        nxt = []
+    while frontier.size:
         d += 1
-        for u in frontier:
-            for v in g.col_idx[g.row_ptr[u]: g.row_ptr[u + 1]]:
-                if dist[v] == np.inf:
-                    dist[v] = d
-                    nxt.append(int(v))
-        frontier = nxt
+        lo = g.row_ptr[frontier]
+        cnt = g.row_ptr[frontier + 1] - lo
+        # edge positions of the frontier: lo_i + 0..cnt_i-1, concatenated
+        start = np.repeat(lo - (np.cumsum(cnt) - cnt), cnt)
+        nbr = g.col_idx[start + np.arange(start.shape[0])]
+        frontier = np.unique(nbr[dist[nbr] == np.inf]).astype(np.int64)
+        dist[frontier] = d
     return dist
 
 

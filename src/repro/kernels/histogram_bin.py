@@ -1,11 +1,11 @@
 """Histogram binning kernel (the paper's Histo app hot loop).
 
 TPU adaptation: instead of per-element scatter (no efficient arbitrary
-scatter on the VPU), each (record-block, bin-block) grid cell builds a
-one-hot membership matrix in VMEM and reduces over records — turning the
-bin update into dense vector ops the VPU/MXU execute at full width.  The
-output bin-block is revisited across record blocks (reduction grid dim is
-innermost), accumulating in place.
+scatter on the VPU), records are sorted by bin and each (record-block,
+bin-block) grid step builds a one-hot membership matrix in VMEM and
+reduces over records — the bin update becomes dense vector ops.  This is
+the delivery kernel's count output (``deliver_fused``)
+with zero-valued records.
 """
 from __future__ import annotations
 
@@ -13,50 +13,20 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-DEFAULT_BLOCK_R = 1024    # records per grid step
-DEFAULT_BLOCK_B = 512     # bins per grid step (4 x 128 lanes)
+from .deliver_fused import deliver_fused
 
 
-def _kernel(idx_ref, out_ref, *, block_b: int):
-    r = pl.program_id(1)
-
-    @pl.when(r == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    b = pl.program_id(0)
-    idx = idx_ref[...]                       # (1, Rb) int32
-    base = b * block_b
-    local = idx[0] - base                    # (Rb,)
-    # one-hot membership (Rb, Bb); padded records carry idx=-1 => never hit
-    cols = jax.lax.broadcasted_iota(jnp.int32, (local.shape[0], block_b), 1)
-    oh = (local[:, None] == cols).astype(jnp.float32)
-    out_ref[...] += jnp.sum(oh, axis=0, keepdims=True)
-
-
-def histogram_bin(idx: jax.Array, num_bins: int,
-                  block_r: int = DEFAULT_BLOCK_R,
-                  block_b: int = DEFAULT_BLOCK_B,
-                  interpret: bool = True) -> jax.Array:
+def histogram_bin(idx: jax.Array, num_bins: int, interpret: bool = True,
+                  **blocks) -> jax.Array:
     """Count occurrences of each bin id.  idx: (N,) int32 in [0, num_bins)
     (negative = padding, ignored).  Returns (num_bins,) float32 counts."""
-    n = idx.shape[0]
-    n_pad = -(-n // block_r) * block_r
-    b_pad = -(-num_bins // block_b) * block_b
-    idx2 = jnp.full((n_pad,), -1, jnp.int32).at[:n].set(idx.astype(jnp.int32))
-    idx2 = idx2.reshape(n_pad // block_r, block_r)
-    nb, nr = b_pad // block_b, n_pad // block_r
-    out = pl.pallas_call(
-        functools.partial(_kernel, block_b=block_b),
-        grid=(nb, nr),
-        in_specs=[pl.BlockSpec((1, block_r), lambda b, r: (r, 0))],
-        out_specs=pl.BlockSpec((1, block_b), lambda b, r: (0, b)),
-        out_shape=jax.ShapeDtypeStruct((1, b_pad), jnp.float32),
-        interpret=interpret,
-    )(idx2)
-    return out[0, :num_bins]
+    # the counts do not depend on the combine; "min" needs no stable
+    # record order, so the sort compiles faster
+    _, counts = deliver_fused(idx, jnp.zeros(idx.shape, jnp.float32),
+                              jnp.zeros((num_bins,), jnp.float32), "min",
+                              interpret=interpret, **blocks)
+    return counts
 
 
 def analysis_cases():
@@ -65,6 +35,6 @@ def analysis_cases():
     across record blocks (accumulating add — commutative-safe)."""
     idx = jnp.asarray([0, 5, 5, 2, 7, 0], jnp.int32)
     return [("histogram_bin",
-             functools.partial(histogram_bin, idx, 8, block_r=4,
-                               block_b=8),
+             functools.partial(histogram_bin, idx, 8, rows_r=1, rows_s=1,
+                               lanes=2),
              "add")]

@@ -1,8 +1,9 @@
 """Public jit'd entry points for the Pallas kernels.
 
-``interpret`` defaults to auto: compiled on TPU, interpreted elsewhere
-(this container is CPU-only; interpret=True executes the kernel bodies in
-Python for bit-faithful validation against ref.py).
+``interpret`` defaults to auto: interpreted on the CPU backend (the
+kernel bodies run as plain XLA ops, for bit-faithful validation against
+ref.py) and compiled everywhere else.  A backend that cannot compile a
+kernel fails loudly instead of falling back to the interpreter.
 """
 from __future__ import annotations
 
@@ -24,7 +25,7 @@ BCSR = _sp.BCSR
 
 def _auto_interpret(interpret):
     if interpret is None:
-        return jax.default_backend() != "tpu"
+        return jax.default_backend() == "cpu"
     return interpret
 
 
@@ -40,11 +41,12 @@ def relax(values, mail_val, mail_flag, combine: str = "min", interpret=None):
                      interpret=_auto_interpret(interpret))
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("num_segments", "combine", "interpret"))
+@functools.partial(jax.jit, static_argnames=("num_segments", "combine",
+                                             "presorted", "interpret"))
 def segment_combine(seg, val, num_segments: int, combine: str = "min",
-                    interpret=None):
+                    presorted: bool = False, interpret=None):
     return _sc.segment_combine(seg, val, num_segments, combine,
+                               presorted=presorted,
                                interpret=_auto_interpret(interpret))
 
 

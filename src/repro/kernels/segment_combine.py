@@ -4,12 +4,8 @@ The paper's proxy tile merges all same-destination updates arriving in a
 region (min for SSSP/BFS/WCC, add for PageRank/SPMV/Histo) before
 forwarding one combined record to the owner.  On TPU the proxy store is a
 dense regional buffer; combining a batch of (segment_id, value) records
-into it is a dense segment reduction.
-
-Kernel shape: grid over (segment-blocks, record-blocks), the record dim
-innermost so each output segment-block is revisited and reduced in VMEM.
-Membership is a one-hot compare (VPU); `add` reduces with +=, `min` with
-an elementwise running minimum against masked +inf.
+into it is a dense segment reduction — the delivery kernel
+(``deliver_fused``) run against an identity mailbox.
 """
 from __future__ import annotations
 
@@ -17,71 +13,23 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
-DEFAULT_BLOCK_R = 1024
-DEFAULT_BLOCK_S = 512
-
-_BIG = 3.4e38   # stand-in for +inf (TPU-safe); python float so the kernel
-                # body sees a literal, not a captured traced constant.
-
-
-def _kernel(seg_ref, val_ref, out_ref, *, block_s: int, combine: str):
-    r = pl.program_id(1)
-    s_blk = pl.program_id(0)
-
-    @pl.when(r == 0)
-    def _init():
-        if combine == "min":
-            out_ref[...] = jnp.full_like(out_ref, _BIG)
-        else:
-            out_ref[...] = jnp.zeros_like(out_ref)
-
-    seg = seg_ref[...][0]                     # (Rb,) int32
-    val = val_ref[...][0]                     # (Rb,) float32
-    base = s_blk * block_s
-    local = seg - base
-    cols = jax.lax.broadcasted_iota(jnp.int32, (local.shape[0], block_s), 1)
-    hit = local[:, None] == cols              # (Rb, Sb)
-    if combine == "min":
-        cand = jnp.where(hit, val[:, None], _BIG)
-        out_ref[...] = jnp.minimum(out_ref[...], jnp.min(cand, axis=0,
-                                                         keepdims=True))
-    else:
-        cand = jnp.where(hit, val[:, None], 0.0)
-        out_ref[...] += jnp.sum(cand, axis=0, keepdims=True)
+from .deliver_fused import deliver_fused
 
 
 def segment_combine(seg: jax.Array, val: jax.Array, num_segments: int,
-                    combine: str = "min",
-                    block_r: int = DEFAULT_BLOCK_R,
-                    block_s: int = DEFAULT_BLOCK_S,
-                    interpret: bool = True) -> jax.Array:
+                    combine: str = "min", presorted: bool = False,
+                    interpret: bool = True, **blocks) -> jax.Array:
     """Dense segment reduction.  seg: (N,) int32 in [0, num_segments)
     (negative = padding); val: (N,) float32.  Returns (num_segments,)
     combined values; untouched segments get the combine identity
-    (+inf for min — returned as jnp.inf — and 0 for add)."""
-    assert combine in ("min", "add")
-    n = seg.shape[0]
-    n_pad = -(-n // block_r) * block_r
-    s_pad = -(-num_segments // block_s) * block_s
-    seg2 = jnp.full((n_pad,), -1, jnp.int32).at[:n].set(seg.astype(jnp.int32))
-    val2 = jnp.zeros((n_pad,), jnp.float32).at[:n].set(val.astype(jnp.float32))
-    seg2 = seg2.reshape(n_pad // block_r, block_r)
-    val2 = val2.reshape(n_pad // block_r, block_r)
-    ns, nr = s_pad // block_s, n_pad // block_r
-    out = pl.pallas_call(
-        functools.partial(_kernel, block_s=block_s, combine=combine),
-        grid=(ns, nr),
-        in_specs=[pl.BlockSpec((1, block_r), lambda s, r: (r, 0)),
-                  pl.BlockSpec((1, block_r), lambda s, r: (r, 0))],
-        out_specs=pl.BlockSpec((1, block_s), lambda s, r: (0, s)),
-        out_shape=jax.ShapeDtypeStruct((1, s_pad), jnp.float32),
-        interpret=interpret,
-    )(seg2, val2)
-    out = out[0, :num_segments]
-    if combine == "min":
-        out = jnp.where(out >= _BIG, jnp.inf, out)
+    (+inf for min, 0 for add).  ``presorted``: valid ``seg`` ascending,
+    padding after it (the engine's grouped record streams)."""
+    ident = jnp.inf if combine == "min" else 0.0
+    out, _ = deliver_fused(seg, val,
+                           jnp.full((num_segments,), ident, jnp.float32),
+                           combine, presorted=presorted,
+                           interpret=interpret, **blocks)
     return out
 
 
@@ -97,13 +45,13 @@ def analysis_cases():
     # branches produce), still multi-block over the record dim
     wseg = jnp.asarray([4, -1, 0, 4, -1, 6], jnp.int32)
     wval = jnp.arange(6, dtype=jnp.float32) + 0.5
+    tiny = dict(rows_r=1, rows_s=1, lanes=2)
     return ([(f"segment_combine:{c}",
-              functools.partial(segment_combine, seg, val, 8, c,
-                                block_r=4, block_s=8),
+              functools.partial(segment_combine, seg, val, 8, c, **tiny),
               c)
              for c in ("min", "add")]
             + [(f"segment_combine:compact:{c}",
                 functools.partial(segment_combine, wseg, wval, 8, c,
-                                  block_r=4, block_s=8),
+                                  **tiny),
                 c)
                for c in ("min", "add")])

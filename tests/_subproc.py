@@ -1,4 +1,8 @@
-"""Run a python snippet in a subprocess with N fake XLA devices."""
+"""Run a python snippet in a subprocess with N fake XLA devices.
+
+The child is pinned to the CPU backend: N virtual host devices are the
+point, and a child must never try to open an accelerator its parent (or
+another test worker) may hold."""
 import os
 import subprocess
 import sys
@@ -7,6 +11,7 @@ SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
 HEADER = """\
 import os
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={n}"
 import warnings
 warnings.filterwarnings("ignore")

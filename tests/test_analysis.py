@@ -154,6 +154,34 @@ class TestPallasRaces:
         disjoint = _call(lambda i: i)
         assert pallas_races.check_call(disjoint, "overwrite", "t") == []
 
+    @pytest.mark.parametrize("index_map,grid,block,tables,combine,want", [
+        # one contiguous run per window (the revisit-accumulate idiom)
+        (lambda i: i // 2, (4,), (4,), None, "add", []),
+        # window 0 revisited after window 1 ran: stale write-back
+        (lambda i: i % 2, (4,), (4,), None, "add", ["split-revisit"]),
+        # the second half of the output is never written
+        (lambda i: 0, (2,), (4,), None, "add", ["unwritten-window"]),
+        # work-list grids are judged on the tables the call was given
+        (lambda i, t: t[i], (4,), (4,), (np.array([0, 0, 1, 1]),), "add",
+         []),
+        (lambda i, t: t[i], (4,), (4,), (np.array([0, 1, 0, 1]),), "add",
+         ["split-revisit"]),
+        (lambda i, t: t[i], (4,), (4,), (np.array([1, 1, 1, 1]),), "add",
+         ["unwritten-window"]),
+    ])
+    def test_revisit_and_coverage_rules(self, index_map, grid, block,
+                                        tables, combine, want):
+        call = _call(index_map, grid=grid, block=block)
+        if tables is not None:
+            call.n_prefetch, call.prefetch = len(tables), tables
+        assert _rules(pallas_races.check_call(call, combine, "t")) == want
+
+    def test_untraceable_prefetch_is_reported(self):
+        call = _call(lambda i, t: t[i])
+        call.n_prefetch = 1                   # tables never captured
+        fs = pallas_races.check_call(call, "add", "t")
+        assert _rules(fs) == ["prefetch-unknown"]
+
     def test_no_pallas_call_is_vacuous(self):
         fs = pallas_races.check_fn(lambda: None, "add", "t")
         assert _rules(fs) == ["no-pallas-call"]

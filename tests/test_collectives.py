@@ -81,7 +81,8 @@ from repro.training.train_step import TrainState, make_train_step
 from repro.launch.shardings import (batch_spec, opt_spec, param_spec,
                                     tree_shardings)
 from jax.sharding import NamedSharding, PartitionSpec as P
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+mesh = jax.make_mesh((2, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 cfg, fam = registry.get("deepseek-7b", smoke=True)
 opt = adamw(lr=1e-3)
 params = fam["init"](cfg, jax.random.PRNGKey(0))
@@ -131,7 +132,8 @@ batch = dict(tokens=jnp.asarray(rng.integers(0, cfg.vocab, (4, 16)), jnp.int32),
 # single device
 _, m0 = jax.jit(make_train_step(cfg, fam, opt))(state, batch)
 # 4-device mesh
-mesh = jax.make_mesh((2, 2), ("data", "model"))
+mesh = jax.make_mesh((2, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 sshard = TrainState(
     params=tree_shardings(params, param_spec, mesh, fsdp=False),
     opt_state=tree_shardings(state.opt_state, opt_spec, mesh, fsdp=False),
