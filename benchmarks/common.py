@@ -37,7 +37,4 @@ def timed(fn, *args, **kw):
     t0 = time.time()
     out = fn(*args, **kw)
     us = (time.time() - t0) * 1e6
-    from repro.obs import default_registry
-    name = getattr(fn, "__name__", "call")
-    default_registry().histogram(f"bench.{name}.us").observe(us)
     return out, us

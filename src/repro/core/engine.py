@@ -79,7 +79,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import time
 from typing import Optional
 
 import jax
@@ -88,7 +87,7 @@ import numpy as np
 
 from . import netstats
 from ..obs.metrics import default_registry
-from ..obs.timeline import ChunkSpan, RunMeta
+from ..obs.timeline import ChunkSpan, HostSpan, RunMeta
 from .costmodel import (CLOCK_GHZ, PU_OPS_PER_EDGE, PU_OPS_PER_RECORD, DCRA_SRAM,
                         PackageConfig, link_provisioning, step_cycles)
 from .netstats import MSG_BITS, SuperstepTrace, TrafficCounters
@@ -305,28 +304,30 @@ class DataLocalEngine:
     def init_state(self, seed_idx=None, seed_val=None,
                    values: Optional[np.ndarray] = None):
         self._require_mono("init_state")
-        ident = jnp.float32(self.app.identity)
-        st = dict(
-            values=jnp.full((self.Nd,), ident) if values is None
-            else jnp.asarray(_pad(values, self.Nd, self.app.identity), jnp.float32),
-            mail_val=jnp.full((self.Nd,), ident),
-            mail_flag=jnp.zeros((self.Nd,), jnp.bool_),
-            cur_lo=jnp.zeros((self.Ns,), jnp.int32),
-            cur_hi=jnp.zeros((self.Ns,), jnp.int32),
-            cur_val=jnp.zeros((self.Ns,), jnp.float32),
-        )
-        if self.cfg.proxy is not None:
-            tags, vals = make_pcache(self.cfg.grid, self.cfg.proxy,
-                                     self.app.identity)
-            st["p_tag"], st["p_val"] = tags, vals
-        self._n_seeds = 0   # mailbox seeds, for the sanitizer's consumed-bound
-        if seed_idx is not None:
-            si = jnp.asarray(np.atleast_1d(seed_idx), jnp.int32)
-            sv = jnp.asarray(np.atleast_1d(seed_val), jnp.float32)
-            st["mail_val"] = st["mail_val"].at[si].set(sv)
-            st["mail_flag"] = st["mail_flag"].at[si].set(True)
-            self._n_seeds = int(si.shape[0])
-        return st
+        with HostSpan("engine.init_state"):
+            ident = jnp.float32(self.app.identity)
+            st = dict(
+                values=jnp.full((self.Nd,), ident) if values is None
+                else jnp.asarray(_pad(values, self.Nd, self.app.identity),
+                                 jnp.float32),
+                mail_val=jnp.full((self.Nd,), ident),
+                mail_flag=jnp.zeros((self.Nd,), jnp.bool_),
+                cur_lo=jnp.zeros((self.Ns,), jnp.int32),
+                cur_hi=jnp.zeros((self.Ns,), jnp.int32),
+                cur_val=jnp.zeros((self.Ns,), jnp.float32),
+            )
+            if self.cfg.proxy is not None:
+                tags, vals = make_pcache(self.cfg.grid, self.cfg.proxy,
+                                         self.app.identity)
+                st["p_tag"], st["p_val"] = tags, vals
+            self._n_seeds = 0   # mailbox seeds: the sanitizer's consumed-bound
+            if seed_idx is not None:
+                si = jnp.asarray(np.atleast_1d(seed_idx), jnp.int32)
+                sv = jnp.asarray(np.atleast_1d(seed_val), jnp.float32)
+                st["mail_val"] = st["mail_val"].at[si].set(sv)
+                st["mail_flag"] = st["mail_flag"].at[si].set(True)
+                self._n_seeds = int(si.shape[0])
+            return st
 
     def activate_all(self, state, cur_val):
         """Epoch-style activation (PageRank/SPMV/Histogram): every source
@@ -402,6 +403,7 @@ class DataLocalEngine:
             return jnp.ones_like(cval)
         raise ValueError(app.edge_value)
 
+    @jax.named_scope("front")
     def _front_dense(self, graph, state, tile_gids):
         """Dense IQ drain + OQ emit over all T tiles (the oracle path).
 
@@ -482,6 +484,7 @@ class DataLocalEngine:
                 consumed_per_tile, total_take, consumed_per_tile,
                 total_take, dst, cand, emit_mask, src_tile)
 
+    @jax.named_scope("front")
     def _front_compact(self, graph, state, chip_id, active, W):
         """Compacted IQ drain + OQ emit over a W-tile active window.
 
@@ -742,6 +745,7 @@ class DataLocalEngine:
         return new_state, stats, off
 
     # ------------------------------------------------------- owner delivery
+    @jax.named_scope("delivery")
     def _drain_to_owners(self, mail_val, mail_flag, dst, val, mask, src,
                          chip_id, region_dims, is_min):
         """Charge the owner-bound leg, deliver on-window records into the
@@ -779,6 +783,7 @@ class DataLocalEngine:
         return mail_val, mail_flag, owner_leg, off_ch, per_tile, off
 
     # --------------------------------------------------------- proxy stage
+    @jax.named_scope("proxy")
     def _proxy_stage(self, mail_val, mail_flag, p_tag, p_val, dst, cand,
                      emit_mask, src_tile, owner, flush, is_min, ident,
                      chip_id, tile_gids):
@@ -937,6 +942,7 @@ class DataLocalEngine:
         return mail_val, mail_flag, p_tag, p_val, charges, pstats, dmax, off
 
     # --------------------------------------------------------- flush drain
+    @jax.named_scope("delivery")
     def _flush_drain(self, flush, p_tag, p_val, mail_val, mail_flag,
                      tile_gids, ident, is_min, chip_id, rdims):
         """Write-back whole-P$ spill as its own ``lax.cond`` leg.
@@ -1017,6 +1023,7 @@ class DataLocalEngine:
         return base * (2 + self._cascade_levels)
 
     # ------------------------------------------------------- cascaded drain
+    @jax.named_scope("delivery")
     def _cascade_drain(self, mail_val, mail_flag, dst, val, src, mask,
                        eligible, is_min, chip_id):
         """Drain proxy-stage output through the region reduction tree.
@@ -1176,22 +1183,35 @@ class DataLocalEngine:
         the existing host-accounting boundary, and ``on_run_end`` with
         the RunResult.  Attaching one adds no host syncs and leaves
         counters/trace/final state bit-identical."""
-        self._require_mono("run")
-        cfg = self.cfg
-        maxs = max_supersteps or cfg.max_supersteps
-        K = cfg.run_chunk if chunk is None else int(chunk)
-        counters = TrafficCounters()
-        trace = SuperstepTrace(double_buffer=cfg.double_buffer)
-        cycles = 0.0
-        steps = 0
-        pkg = cfg.pkg
-        links = link_provisioning(cfg.grid, pkg)
-        values_before = state["values"] if cfg.sanitize else None
-        if observer is not None:
-            observer.on_run_start(RunMeta(
-                app=self.app.name, grid_ny=cfg.grid.ny, grid_nx=cfg.grid.nx,
-                chunk=K, backend=cfg.backend, sanitize=cfg.sanitize,
-                telemetry=cfg.telemetry, pkg=pkg, grid=cfg.grid))
+        with HostSpan("engine.run_start"):
+            self._require_mono("run")
+            cfg = self.cfg
+            maxs = max_supersteps or cfg.max_supersteps
+            K = cfg.run_chunk if chunk is None else int(chunk)
+            counters = TrafficCounters()
+            trace = SuperstepTrace(double_buffer=cfg.double_buffer)
+            cycles = 0.0
+            steps = 0
+            pkg = cfg.pkg
+            links = link_provisioning(cfg.grid, pkg)
+            fill = links["diameter"] * 0.5                 # pipeline fill
+            values_before = state["values"] if cfg.sanitize else None
+            if observer is not None:
+                observer.on_run_start(RunMeta(
+                    app=self.app.name, grid_ny=cfg.grid.ny,
+                    grid_nx=cfg.grid.nx, chunk=K, backend=cfg.backend,
+                    sanitize=cfg.sanitize, telemetry=cfg.telemetry,
+                    pkg=pkg, grid=cfg.grid))
+            if K > 0:
+                progress = _ProgressReporter(self.app.name, progress_every,
+                                             sanitize=cfg.sanitize,
+                                             tiles=self.T)
+                if self._stat_names is None:   # one abstract trace per engine
+                    self._stat_names = _stat_keys(
+                        self._chunk_step_one, state,
+                        jnp.zeros((), jnp.bool_))
+                chunk_fn = functools.partial(self._chunk, self.graph,
+                                             length=K)
 
         def account(stats):
             """Legacy-loop per-superstep accounting.  The chunked branch
@@ -1206,56 +1226,51 @@ class DataLocalEngine:
             # ---- BSP time model for this superstep ----------------------
             step_cycles = superstep_cycles(stats, pkg, links)
             if step_cycles > 0 or stats["pending"] > 0:
-                cycles += step_cycles + links["diameter"] * 0.5  # pipeline fill
+                cycles += step_cycles + fill
+
+        def add_chunk_cycles(stacked, n_act, cycles):
+            # vectorized BSP terms, accumulated in execution order —
+            # bit-identical to account() per step
+            if cfg.sanitize:
+                bad = stacked.get("sanity_violations")
+                if bad is not None:
+                    _sanitize_gate(cfg, self.app.name,
+                                   float(np.sum(bad[:n_act])))
+            sc = chunk_cycles(stacked, n_act, pkg, links)
+            pend = np.asarray(stacked["pending"][:n_act])
+            for s, p in zip(sc.tolist(), pend.tolist()):
+                if s > 0 or p > 0:
+                    cycles += s + fill
+            return cycles
 
         if K <= 0:
             state, steps = self._run_legacy(state, maxs, progress_every,
                                             account, observer=observer)
         else:
-            progress = _ProgressReporter(self.app.name, progress_every,
-                                         sanitize=cfg.sanitize,
-                                         tiles=self.T)
-            fill = links["diameter"] * 0.5
-            if self._stat_names is None:   # one abstract trace per engine
-                self._stat_names = _stat_keys(self._chunk_step_one, state,
-                                              jnp.zeros((), jnp.bool_))
-
-            def add_chunk_cycles(stacked, n_act, cycles):
-                # vectorized BSP terms, accumulated in execution order —
-                # bit-identical to account() per step
-                if cfg.sanitize:
-                    bad = stacked.get("sanity_violations")
-                    if bad is not None:
-                        _sanitize_gate(cfg, self.app.name,
-                                       float(np.sum(bad[:n_act])))
-                sc = chunk_cycles(stacked, n_act, pkg, links)
-                pend = np.asarray(stacked["pending"][:n_act])
-                for s, p in zip(sc.tolist(), pend.tolist()):
-                    if s > 0 or p > 0:
-                        cycles += s + fill
-                return cycles
-
-            chunk_fn = functools.partial(self._chunk, self.graph, length=K)
             state, steps, cycles = _drain_chunked(
                 chunk_fn, state, maxs, self._stat_names, counters, trace,
                 cfg.element_bits, progress, add_chunk_cycles, cycles,
                 observer=observer)
-        counters.supersteps = steps
-        time_s = cycles / (CLOCK_GHZ * 1e9)
-        result = RunResult(counters=counters, cycles=cycles, time_s=time_s,
-                           supersteps=steps, trace=trace)
-        if cfg.sanitize:
-            from ..analysis import invariants as _inv
-            write_back = cfg.proxy is not None and cfg.proxy.write_back
-            findings = _inv.check_run(
-                result, pkg=pkg, grid=cfg.grid,
-                where=f"sanitize/{self.app.name}", write_back=write_back,
-                seeds=self._n_seeds, combine=self.app.combine,
-                values_before=values_before, values_after=state["values"],
-                drained=steps < maxs)
-            _inv.assert_clean(findings, context=f"run({self.app.name})")
-        if observer is not None:
-            observer.on_run_end(result)
+        with HostSpan("engine.finish"):
+            counters.supersteps = steps
+            time_s = cycles / (CLOCK_GHZ * 1e9)
+            result = RunResult(counters=counters, cycles=cycles,
+                               time_s=time_s, supersteps=steps, trace=trace)
+            if cfg.sanitize:
+                from ..analysis import invariants as _inv
+                write_back = (cfg.proxy is not None
+                              and cfg.proxy.write_back)
+                findings = _inv.check_run(
+                    result, pkg=pkg, grid=cfg.grid,
+                    where=f"sanitize/{self.app.name}",
+                    write_back=write_back, seeds=self._n_seeds,
+                    combine=self.app.combine,
+                    values_before=values_before,
+                    values_after=state["values"], drained=steps < maxs)
+                _inv.assert_clean(findings,
+                                  context=f"run({self.app.name})")
+            if observer is not None:
+                observer.on_run_end(result)
         return state, result
 
     def _run_legacy(self, state, maxs, progress_every, account,
@@ -1272,18 +1287,19 @@ class DataLocalEngine:
         steps = 0
         flush_flag = jnp.asarray(False)
         while steps < maxs:
-            t0 = time.perf_counter()
-            state, stats = self._superstep(self.graph, state, flush_flag)
-            t1 = time.perf_counter()
-            stats = jax.device_get(stats)
-            sync_ctr.inc()
-            t2 = time.perf_counter()
+            at = dict(chunk=steps, step=steps)
+            with HostSpan("engine.dispatch", **at) as t_dispatch:
+                state, stats = self._superstep(self.graph, state,
+                                               flush_flag)
+            with HostSpan("engine.fetch", **at) as t_fetch:
+                stats = jax.device_get(stats)
+                sync_ctr.inc()
             steps += 1
-            account(stats)
-            t3 = time.perf_counter()
+            with HostSpan("engine.account", **at) as t_account:
+                account(stats)
             if observer is not None:
-                observer.on_chunk(_legacy_span(steps, stats, (t0, t1),
-                                               (t1, t2), (t2, t3)))
+                observer.on_chunk(_legacy_span(steps, stats, t_dispatch.t,
+                                               t_fetch.t, t_account.t))
             if flush_flag:
                 flush_flag = jnp.asarray(False)
             if stats["pending"] == 0:
@@ -1478,39 +1494,42 @@ def _drain_chunked(chunk_fn, state, maxs, keys, counters, trace,
         jnp.asarray(flush0, jnp.bool_)
     done = jnp.zeros((), jnp.bool_)
     while steps < maxs:
-        t0 = time.perf_counter()
-        (state, flush, done, _), (packed, ints, vecs) = chunk_fn(
-            state, flush, done, jnp.int32(maxs - steps))
-        t1 = time.perf_counter()
-        # the single host sync of this chunk:
-        host_done, packed, ints, vecs = jax.device_get(
-            (done, packed, ints, vecs))
-        sync_ctr.inc()
-        t2 = time.perf_counter()
-        stacked = {k: packed[:, i] for i, k in enumerate(keys)}
-        for i, k in enumerate(_EXACT_INT_STATS):
-            stacked[k] = ints[:, i]          # exact int32, not the f32 row
-        n_act = int(np.sum(stacked["active"]))
-        if n_act:
-            counters.add(chunk_counters(stacked, n_act))
-            trace.append_chunk(stacked, n_act, element_bits=element_bits)
-            cycles = add_chunk_cycles(stacked, n_act, cycles)
-            if vec_sums is not None:
-                for k, v in vecs.items():
-                    s = np.sum(np.asarray(v[:n_act], np.float64), axis=0)
-                    vec_sums[k] = vec_sums.get(k, 0.0) + s
-        t3 = time.perf_counter()
+        at = dict(chunk=chunk_idx, step=steps)
+        with HostSpan("engine.dispatch", **at) as t_dispatch:
+            (state, flush, done, _), (packed, ints, vecs) = chunk_fn(
+                state, flush, done, jnp.int32(maxs - steps))
+        with HostSpan("engine.fetch", **at) as t_fetch:
+            # the single host sync of this chunk:
+            host_done, packed, ints, vecs = jax.device_get(
+                (done, packed, ints, vecs))
+            sync_ctr.inc()
+        with HostSpan("engine.account", **at) as t_account:
+            stacked = {k: packed[:, i] for i, k in enumerate(keys)}
+            for i, k in enumerate(_EXACT_INT_STATS):
+                stacked[k] = ints[:, i]      # exact int32, not the f32 row
+            n_act = int(np.sum(stacked["active"]))
+            if n_act:
+                counters.add(chunk_counters(stacked, n_act))
+                trace.append_chunk(stacked, n_act, element_bits=element_bits)
+                cycles = add_chunk_cycles(stacked, n_act, cycles)
+                if vec_sums is not None:
+                    for k, v in vecs.items():
+                        s = np.sum(np.asarray(v[:n_act], np.float64), axis=0)
+                        vec_sums[k] = vec_sums.get(k, 0.0) + s
         if observer is not None:
             observer.on_chunk(ChunkSpan(
                 index=chunk_idx, step_lo=steps, step_hi=steps + n_act,
-                t_dispatch=(t0, t1), t_fetch=(t1, t2), t_account=(t2, t3),
+                t_dispatch=t_dispatch.t, t_fetch=t_fetch.t,
+                t_account=t_account.t,
                 stats={k: np.asarray(v[:n_act]) for k, v in stacked.items()},
                 vecs={k: np.asarray(v[:n_act]) for k, v in vecs.items()}))
         steps += n_act
         chunk_idx += 1
         progress.report(steps, stacked, n_act)
         if boundary is not None:
-            cycles = boundary(steps, state, flush, bool(host_done), cycles)
+            with HostSpan("engine.boundary", **at):
+                cycles = boundary(steps, state, flush, bool(host_done),
+                                  cycles)
         if host_done or n_act == 0:
             break
     return state, steps, cycles
@@ -1713,6 +1732,7 @@ class _ProgressReporter:
             self._next += self.every
 
 
+@jax.named_scope("delivery")
 def _deliver(mail_val, mail_flag, dst, val, mask, owner, T, Nd, is_min,
              backend: str = "jnp"):
     """Combine records into owner mailboxes; returns the (T,) per-tile
@@ -1752,6 +1772,7 @@ def _deliver(mail_val, mail_flag, dst, val, mask, owner, T, Nd, is_min,
     return mv, mf, per_tile.astype(jnp.float32)
 
 
+@jax.named_scope("delivery")
 def _deliver_pallas(mail_val, mail_flag, dst, val, mask, owner, T, Nd,
                     is_min):
     """Pallas rendering of the owner delivery in one launch

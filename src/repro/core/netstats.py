@@ -18,6 +18,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Dict, List
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -248,6 +249,7 @@ class SuperstepTrace:
         return t
 
 
+@jax.named_scope("charge")
 def charge(grid: TileGrid, src_tid, dst_tid, mask, region_dims=None):
     """Vectorised traffic charge for a batch of messages.
 
@@ -289,6 +291,7 @@ def charge(grid: TileGrid, src_tid, dst_tid, mask, region_dims=None):
     )
 
 
+@jax.named_scope("charge")
 def charge_off_chip(part, src_tid, dst_tid, mask):
     """Charge the off-chip network leg for records leaving their chip.
 

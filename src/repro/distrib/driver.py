@@ -54,7 +54,6 @@ from __future__ import annotations
 import dataclasses
 import functools
 import tempfile
-import time
 from typing import Optional
 
 import jax
@@ -80,7 +79,7 @@ from ..core.netstats import MSG_BITS, SuperstepTrace, TrafficCounters
 from ..core.proxy import chip_local_proxy
 from ..core.tilegrid import ChipPartition, TileGrid, partition_grid
 from ..obs.metrics import default_registry
-from ..obs.timeline import RunMeta
+from ..obs.timeline import HostSpan, RunMeta
 from .mesh import ExecMesh
 
 
@@ -149,6 +148,7 @@ def _merge_flags(mail_flag, flat, mask, seg, n_seg):
     return mf, recv
 
 
+@jax.named_scope("exchange")
 def _fold_bank(state, is_min):
     """Apply the deferred mailbox-value scatter of the double-buffered
     exchange (bank keys ``_db_idx`` / ``_db_val`` / ``_db_mask``) and
@@ -208,6 +208,7 @@ def exchange(part: ChipPartition, chunk_dst: int, state, off, is_min: bool):
     return state, recv.reshape(C, Tl)
 
 
+@jax.named_scope("exchange")
 def _aggregate(stats, recv, telemetry: bool = False, mesh=None):
     """Reduce per-chip superstep stats to grid-global ones: traffic sums,
     bottleneck (per-tile) maxima; exchange receive contention (``recv``,
@@ -508,33 +509,35 @@ class DistributedEngine:
     # ---------------------------------------------------------------- state
     def init_state(self, seed_idx=None, seed_val=None,
                    values: Optional[np.ndarray] = None):
-        k = self.kernel
-        ident = self.app.identity
-        vals_g = (np.full((k.Ngd,), ident, np.float32) if values is None
-                  else np.asarray(_pad(np.asarray(values, np.float32),
-                                       k.Ngd, ident), np.float32))
-        mail_val_g = np.full((k.Ngd,), ident, np.float32)
-        mail_flag_g = np.zeros((k.Ngd,), bool)
-        self._n_seeds = 0   # mailbox seeds, for the sanitizer's consumed-bound
-        if seed_idx is not None:
-            si = np.atleast_1d(np.asarray(seed_idx)).astype(np.int64)
-            sv = np.atleast_1d(np.asarray(seed_val)).astype(np.float32)
-            mail_val_g[si] = sv
-            mail_flag_g[si] = True
-            self._n_seeds = int(si.shape[0])
-        st = dict(
-            values=self._shard(vals_g, self.Cd),
-            mail_val=self._shard(mail_val_g, self.Cd),
-            mail_flag=self._shard(mail_flag_g, self.Cd),
-            cur_lo=jnp.zeros((self.C, k.Ns), jnp.int32),
-            cur_hi=jnp.zeros((self.C, k.Ns), jnp.int32),
-            cur_val=jnp.zeros((self.C, k.Ns), jnp.float32),
-        )
-        if self.cfg.proxy is not None:
-            S = self.cfg.proxy.slots
-            st["p_tag"] = jnp.full((self.C, self.Tl, S), -1, jnp.int32)
-            st["p_val"] = jnp.full((self.C, self.Tl, S), ident, jnp.float32)
-        return st
+        with HostSpan("engine.init_state"):
+            k = self.kernel
+            ident = self.app.identity
+            vals_g = (np.full((k.Ngd,), ident, np.float32) if values is None
+                      else np.asarray(_pad(np.asarray(values, np.float32),
+                                           k.Ngd, ident), np.float32))
+            mail_val_g = np.full((k.Ngd,), ident, np.float32)
+            mail_flag_g = np.zeros((k.Ngd,), bool)
+            self._n_seeds = 0   # mailbox seeds: the sanitizer's consumed-bound
+            if seed_idx is not None:
+                si = np.atleast_1d(np.asarray(seed_idx)).astype(np.int64)
+                sv = np.atleast_1d(np.asarray(seed_val)).astype(np.float32)
+                mail_val_g[si] = sv
+                mail_flag_g[si] = True
+                self._n_seeds = int(si.shape[0])
+            st = dict(
+                values=self._shard(vals_g, self.Cd),
+                mail_val=self._shard(mail_val_g, self.Cd),
+                mail_flag=self._shard(mail_flag_g, self.Cd),
+                cur_lo=jnp.zeros((self.C, k.Ns), jnp.int32),
+                cur_hi=jnp.zeros((self.C, k.Ns), jnp.int32),
+                cur_val=jnp.zeros((self.C, k.Ns), jnp.float32),
+            )
+            if self.cfg.proxy is not None:
+                S = self.cfg.proxy.slots
+                st["p_tag"] = jnp.full((self.C, self.Tl, S), -1, jnp.int32)
+                st["p_val"] = jnp.full((self.C, self.Tl, S), ident,
+                                       jnp.float32)
+            return st
 
     def activate_all(self, state, cur_val):
         state = dict(state)
@@ -654,38 +657,40 @@ class DistributedEngine:
                 # so hub skew cannot overflow a send buffer; identity
                 # gather on one device — the stacked stream is already
                 # global and the scatter indices match the emulation)
-                g_dst, g_val, g_mask = mesh.gather_records(
-                    (off["dst"].reshape(-1), off["val"].reshape(-1),
-                     off["mask"].reshape(-1)))
-                ochip, ltile, off_idx = _owner_slots(part, Cd, g_dst)
-                mine = g_mask & (ochip // per == mesh.axis_index())
-                lane = ochip % per
-                flat = lane * Nld + off_idx
-                seg = lane * Tl + ltile
-                if double_buffer:
-                    mf, recv = _merge_flags(
-                        new_state["mail_flag"].reshape(-1), flat, mine,
-                        seg, per * Tl)
-                    new_state = dict(new_state,
-                                     mail_flag=mf.reshape(per, Nld),
-                                     _db_idx=flat, _db_val=g_val,
-                                     _db_mask=mine)
-                else:
-                    mv, mf, recv = _combine_into_mail(
-                        new_state["mail_val"].reshape(-1),
-                        new_state["mail_flag"].reshape(-1),
-                        flat, mine, g_val, seg, per * Tl, is_min)
-                    new_state = dict(new_state,
-                                     mail_val=mv.reshape(per, Nld),
-                                     mail_flag=mf.reshape(per, Nld))
-                recv = recv.reshape(per, Tl)
+                with jax.named_scope("exchange"):
+                    g_dst, g_val, g_mask = mesh.gather_records(
+                        (off["dst"].reshape(-1), off["val"].reshape(-1),
+                         off["mask"].reshape(-1)))
+                    ochip, ltile, off_idx = _owner_slots(part, Cd, g_dst)
+                    mine = g_mask & (ochip // per == mesh.axis_index())
+                    lane = ochip % per
+                    flat = lane * Nld + off_idx
+                    seg = lane * Tl + ltile
+                    if double_buffer:
+                        mf, recv = _merge_flags(
+                            new_state["mail_flag"].reshape(-1), flat, mine,
+                            seg, per * Tl)
+                        new_state = dict(new_state,
+                                         mail_flag=mf.reshape(per, Nld),
+                                         _db_idx=flat, _db_val=g_val,
+                                         _db_mask=mine)
+                    else:
+                        mv, mf, recv = _combine_into_mail(
+                            new_state["mail_val"].reshape(-1),
+                            new_state["mail_flag"].reshape(-1),
+                            flat, mine, g_val, seg, per * Tl, is_min)
+                        new_state = dict(new_state,
+                                         mail_val=mv.reshape(per, Nld),
+                                         mail_flag=mf.reshape(per, Nld))
+                    recv = recv.reshape(per, Tl)
             else:                       # 1x1 partition: nothing can leave
                 recv = None
             agg = _aggregate(stats, recv, telemetry, mesh)
             # pending must see the post-exchange mailbox flags: a record
             # that crossed chips this superstep is the next superstep's
             # work (flags merge eagerly even when double-buffered)
-            agg["pending"] = mesh.psum(_pending(new_state))
+            with jax.named_scope("exchange"):
+                agg["pending"] = mesh.psum(_pending(new_state))
             return new_state, agg
 
         return step
@@ -790,51 +795,81 @@ class DistributedEngine:
         recovery overhead priced separately (see trace.recovery_events).
         ``ckpt_dir`` overrides the checkpoint directory (default: a
         fresh temp dir per run)."""
-        cfg, part = self.cfg, self.part
-        maxs = max_supersteps or cfg.max_supersteps
-        K = cfg.run_chunk if chunk is None else int(chunk)
-        if observer is not None:
-            observer.on_run_start(RunMeta(
-                app=self.app.name, grid_ny=cfg.grid.ny, grid_nx=cfg.grid.nx,
-                n_chips=self.C, chips_y=part.chips_y, chips_x=part.chips_x,
-                chunk=K, backend=self.backend, sanitize=cfg.sanitize,
-                telemetry=cfg.telemetry, pkg=cfg.pkg, grid=cfg.grid,
-                n_devices=self.mesh.ndev))
-        counters = TrafficCounters()
-        cycles = 0.0
-        steps = 0
-        pkg = cfg.pkg
-        links = link_provisioning(cfg.grid, pkg)
-        cy, cx = part.chips_y, part.chips_x
-        # board links provisioned under the run's own PackageConfig (the
-        # per-axis knobs) — shared formula with costmodel's re-pricing so
-        # pricing the trace under this config reproduces this run's time
-        n_board_links = board_link_provisioning(pkg, cy, cx)
-        db = bool(cfg.double_buffer)
-        trace = SuperstepTrace(board_links=n_board_links,
-                               chips_y=cy, chips_x=cx, double_buffer=db)
-        io_lat_cycles = 2.0 * IO_DIE_RXTX_LAT_NS * CLOCK_GHZ   # Tx + Rx IO die
-        fill = links["diameter"] * 0.5                         # pipeline fill
-        # double-buffer accounting: the exchange leg (board serialization
-        # + IO-die latency) of the previous charged superstep, still in
-        # flight while this superstep computes; the final one drains in
-        # the open (tail charge after the loop).  Stays 0.0 synchronous.
-        prev_exch = [0.0]
-        # recovery overhead (checkpoint legs, discarded replay windows,
-        # re-shard restores) accumulates apart from `cycles` and is added
-        # exactly once after the drain tail — see _FaultTolerance
-        overhead = [0.0]
-        vec_sums = {} if cfg.telemetry else None
-        ft = None
-        if cfg.ckpt_every_supersteps > 0 or fault_injector is not None:
-            ft = _FaultTolerance(
-                self,
-                directory=(ckpt_dir or tempfile.mkdtemp(
-                    prefix=f"repro_ckpt_{self.app.name}_")),
-                every=cfg.ckpt_every_supersteps, injector=fault_injector,
-                counters=counters, trace=trace, prev_exch=prev_exch,
-                overhead=overhead, vec_sums=vec_sums,
-                n_board_links=n_board_links)
+        with HostSpan("engine.run_start"):
+            cfg, part = self.cfg, self.part
+            maxs = max_supersteps or cfg.max_supersteps
+            K = cfg.run_chunk if chunk is None else int(chunk)
+            if observer is not None:
+                observer.on_run_start(RunMeta(
+                    app=self.app.name, grid_ny=cfg.grid.ny,
+                    grid_nx=cfg.grid.nx, n_chips=self.C,
+                    chips_y=part.chips_y, chips_x=part.chips_x, chunk=K,
+                    backend=self.backend, sanitize=cfg.sanitize,
+                    telemetry=cfg.telemetry, pkg=cfg.pkg, grid=cfg.grid,
+                    n_devices=self.mesh.ndev))
+            counters = TrafficCounters()
+            cycles = 0.0
+            steps = 0
+            pkg = cfg.pkg
+            links = link_provisioning(cfg.grid, pkg)
+            cy, cx = part.chips_y, part.chips_x
+            # board links provisioned under the run's own PackageConfig
+            # (the per-axis knobs) — shared formula with costmodel's
+            # re-pricing so pricing the trace under this config
+            # reproduces this run's time
+            n_board_links = board_link_provisioning(pkg, cy, cx)
+            board_div = n_board_links * _off_pkg_bits_per_cycle(pkg)
+            db = bool(cfg.double_buffer)
+            trace = SuperstepTrace(board_links=n_board_links,
+                                   chips_y=cy, chips_x=cx, double_buffer=db)
+            io_lat_cycles = 2.0 * IO_DIE_RXTX_LAT_NS * CLOCK_GHZ  # Tx + Rx
+            fill = links["diameter"] * 0.5                     # pipeline fill
+            # double-buffer accounting: the exchange leg (board
+            # serialization + IO-die latency) of the previous charged
+            # superstep, still in flight while this superstep computes;
+            # the final one drains in the open (tail charge after the
+            # loop).  Stays 0.0 synchronous.
+            prev_exch = [0.0]
+            # recovery overhead (checkpoint legs, discarded replay
+            # windows, re-shard restores) accumulates apart from `cycles`
+            # and is added exactly once after the drain tail — see
+            # _FaultTolerance
+            overhead = [0.0]
+            vec_sums = {} if cfg.telemetry else None
+            ft = None
+            if cfg.ckpt_every_supersteps > 0 or fault_injector is not None:
+                ft = _FaultTolerance(
+                    self,
+                    directory=(ckpt_dir or tempfile.mkdtemp(
+                        prefix=f"repro_ckpt_{self.app.name}_")),
+                    every=cfg.ckpt_every_supersteps,
+                    injector=fault_injector, counters=counters,
+                    trace=trace, prev_exch=prev_exch, overhead=overhead,
+                    vec_sums=vec_sums, n_board_links=n_board_links)
+
+            boundary = None
+            if ft is not None:
+                if K <= 0:
+                    def boundary(bsteps, bstate, bflush, bdone):
+                        nonlocal cycles
+                        cycles = ft.at_boundary(bsteps, bstate, bflush,
+                                                bdone, cycles)
+                else:
+                    boundary = ft.at_boundary
+                ft.checkpoint(0, state, False, cycles)   # step-0 baseline
+            if K > 0:
+                progress = _ProgressReporter(
+                    f"{self.app.name}/{self.C}chips", progress_every,
+                    sanitize=cfg.sanitize, tiles=self.C * self.Tl)
+                # stat layout of the packed scan rows (the vmapped step's
+                # agg carries the same keys the shard_map rendering emits)
+                if self._stat_names is None:   # one abstract trace per engine
+                    raw = self._raw_vmap_step()
+                    self._stat_names = _stat_keys(
+                        lambda st, fl: raw(self._graph_s, st,
+                                           self._chip_ids, fl),
+                        state, jnp.zeros((), jnp.bool_))
+                chunk_fn = self._get_chunk_fn(K)
 
         def account(stats):
             """Legacy-loop per-superstep accounting.  The chunked branch
@@ -873,116 +908,88 @@ class DistributedEngine:
                     if stats.get("off_chip_msgs", 0.0) > 0:
                         cycles += io_lat_cycles
 
-        boundary = None
-        if ft is not None:
-            if K <= 0:
-                def boundary(bsteps, bstate, bflush, bdone):
-                    nonlocal cycles
-                    cycles = ft.at_boundary(bsteps, bstate, bflush, bdone,
-                                            cycles)
-            else:
-                boundary = ft.at_boundary
-            ft.checkpoint(0, state, False, cycles)   # step-0 baseline
+        def add_chunk_cycles(stacked, n_act, cycles):
+            # monolithic BSP terms maxed with the board leg, plus IO-die
+            # latency on supersteps with off-chip records -- accumulated
+            # in execution order like the legacy loop (double-buffered:
+            # each superstep pays max(chip-local work, previous
+            # exchange), its exchange carries over)
+            if cfg.sanitize:
+                bad = stacked.get("sanity_violations")
+                if bad is not None:
+                    _sanitize_gate(cfg, self.app.name,
+                                   float(np.sum(bad[:n_act])))
 
-        if K <= 0:
-            steps0, flush0 = 0, False
-            while True:
-                try:
+            def offvec(key):           # absent on a 1x1 partition
+                a = stacked.get(key)
+                return (np.asarray(a[:n_act], np.float64)
+                        if a is not None else np.zeros(n_act))
+
+            t_board = offvec("off_chip_hop_msgs") * MSG_BITS / board_div
+            core = chunk_cycles(stacked, n_act, pkg, links)
+            pend = np.asarray(stacked["pending"][:n_act])
+            offm = offvec("off_chip_msgs")
+            if db:
+                for c, b, p, o in zip(core.tolist(), t_board.tolist(),
+                                      pend.tolist(), offm.tolist()):
+                    if c > 0 or b > 0 or p > 0:
+                        cycles += max(c, prev_exch[0]) + fill
+                        prev_exch[0] = b + (io_lat_cycles if o > 0
+                                            else 0.0)
+                return cycles
+            sc = np.maximum(core, t_board)
+            for s, p, o in zip(sc.tolist(), pend.tolist(), offm.tolist()):
+                if s > 0 or p > 0:
+                    cycles += s + fill
+                    if o > 0:
+                        cycles += io_lat_cycles
+            return cycles
+
+        steps0, flush0 = 0, False
+        while True:
+            try:
+                if K <= 0:
                     state, steps = self._run_legacy(
                         state, maxs, progress_every, account,
                         observer=observer, steps0=steps0, flush0=flush0,
                         boundary=boundary)
-                    break
-                except ChipLostError as e:
-                    state, flush0, steps0, cycles = ft.recover(e)
-        else:
-            progress = _ProgressReporter(f"{self.app.name}/{self.C}chips",
-                                         progress_every,
-                                         sanitize=cfg.sanitize,
-                                         tiles=self.C * self.Tl)
-            fill = links["diameter"] * 0.5
-            board_div = n_board_links * _off_pkg_bits_per_cycle(pkg)
-            # stat layout of the packed scan rows (the vmapped step's agg
-            # carries the same keys the shard_map rendering emits)
-            if self._stat_names is None:   # one abstract trace per engine
-                raw = self._raw_vmap_step()
-                self._stat_names = _stat_keys(
-                    lambda st, fl: raw(self._graph_s, st, self._chip_ids,
-                                       fl),
-                    state, jnp.zeros((), jnp.bool_))
-            def add_chunk_cycles(stacked, n_act, cycles):
-                # monolithic BSP terms maxed with the board leg, plus
-                # IO-die latency on supersteps with off-chip records --
-                # accumulated in execution order like the legacy loop
-                # (double-buffered: each superstep pays max(chip-local
-                # work, previous exchange), its exchange carries over)
-                if cfg.sanitize:
-                    bad = stacked.get("sanity_violations")
-                    if bad is not None:
-                        _sanitize_gate(cfg, self.app.name,
-                                       float(np.sum(bad[:n_act])))
-
-                def offvec(key):           # absent on a 1x1 partition
-                    a = stacked.get(key)
-                    return (np.asarray(a[:n_act], np.float64)
-                            if a is not None else np.zeros(n_act))
-
-                t_board = offvec("off_chip_hop_msgs") * MSG_BITS / board_div
-                core = chunk_cycles(stacked, n_act, pkg, links)
-                pend = np.asarray(stacked["pending"][:n_act])
-                offm = offvec("off_chip_msgs")
-                if db:
-                    for c, b, p, o in zip(core.tolist(), t_board.tolist(),
-                                          pend.tolist(), offm.tolist()):
-                        if c > 0 or b > 0 or p > 0:
-                            cycles += max(c, prev_exch[0]) + fill
-                            prev_exch[0] = b + (io_lat_cycles if o > 0
-                                                else 0.0)
-                    return cycles
-                sc = np.maximum(core, t_board)
-                for s, p, o in zip(sc.tolist(), pend.tolist(),
-                                   offm.tolist()):
-                    if s > 0 or p > 0:
-                        cycles += s + fill
-                        if o > 0:
-                            cycles += io_lat_cycles
-                return cycles
-
-            steps0, flush0 = 0, False
-            while True:
-                try:
-                    # re-fetched each attempt: a recovery rebuilds the
-                    # mesh, so the compiled chunk fn must be re-bound
-                    chunk_fn = self._get_chunk_fn(K)
+                else:
                     state, steps, cycles = _drain_chunked(
                         chunk_fn, state, maxs, self._stat_names, counters,
                         trace, cfg.element_bits, progress, add_chunk_cycles,
                         cycles, observer=observer, steps0=steps0,
                         flush0=flush0, boundary=boundary,
                         vec_sums=vec_sums)
-                    break
-                except ChipLostError as e:
-                    state, flush0, steps0, cycles = ft.recover(e)
-        cycles += prev_exch[0]   # final in-flight exchange drains in the open
-        cycles += overhead[0]    # recovery legs, priced once at the end
-        counters.supersteps = steps
-        self.last_load_vecs = vec_sums
-        time_s = cycles / (CLOCK_GHZ * 1e9)
-        out_state = dict(state)
-        out_state["values"] = self._gather(state["values"], self.Cd)
-        result = RunResult(counters=counters, cycles=cycles, time_s=time_s,
-                           supersteps=steps, trace=trace)
-        if cfg.sanitize:
-            from ..analysis import invariants as _inv
-            findings = _inv.check_run(
-                result, pkg=pkg, grid=cfg.grid,
-                where=f"sanitize/{self.app.name}/{self.C}chips",
-                write_back=self._write_back,
-                seeds=getattr(self, "_n_seeds", 0), drained=steps < maxs)
-            _inv.assert_clean(
-                findings, context=f"run({self.app.name}, {self.C} chips)")
-        if observer is not None:
-            observer.on_run_end(result)
+                break
+            except ChipLostError as e:
+                state, flush0, steps0, cycles = ft.recover(e)
+                if K > 0:
+                    # the recovery rebuilt the mesh: re-bind the compiled
+                    # chunk fn
+                    chunk_fn = self._get_chunk_fn(K)
+        with HostSpan("engine.finish"):
+            cycles += prev_exch[0]   # final in-flight exchange drains open
+            cycles += overhead[0]    # recovery legs, priced once at the end
+            counters.supersteps = steps
+            self.last_load_vecs = vec_sums
+            time_s = cycles / (CLOCK_GHZ * 1e9)
+            out_state = dict(state)
+            out_state["values"] = self._gather(state["values"], self.Cd)
+            result = RunResult(counters=counters, cycles=cycles,
+                               time_s=time_s, supersteps=steps, trace=trace)
+            if cfg.sanitize:
+                from ..analysis import invariants as _inv
+                findings = _inv.check_run(
+                    result, pkg=pkg, grid=cfg.grid,
+                    where=f"sanitize/{self.app.name}/{self.C}chips",
+                    write_back=self._write_back,
+                    seeds=getattr(self, "_n_seeds", 0),
+                    drained=steps < maxs)
+                _inv.assert_clean(
+                    findings,
+                    context=f"run({self.app.name}, {self.C} chips)")
+            if observer is not None:
+                observer.on_run_end(result)
         return out_state, result
 
     def _run_legacy(self, state, maxs, progress_every, account,
@@ -1003,18 +1010,18 @@ class DistributedEngine:
         steps = int(steps0)
         flush_flag = jnp.asarray(bool(flush0))
         while steps < maxs:
-            t0 = time.perf_counter()
-            state, stats = step_fn(state, flush_flag)
-            t1 = time.perf_counter()
-            stats = jax.device_get(stats)
-            sync_ctr.inc()
-            t2 = time.perf_counter()
+            at = dict(chunk=steps, step=steps)
+            with HostSpan("engine.dispatch", **at) as t_dispatch:
+                state, stats = step_fn(state, flush_flag)
+            with HostSpan("engine.fetch", **at) as t_fetch:
+                stats = jax.device_get(stats)
+                sync_ctr.inc()
             steps += 1
-            account(stats)
-            t3 = time.perf_counter()
+            with HostSpan("engine.account", **at) as t_account:
+                account(stats)
             if observer is not None:
-                observer.on_chunk(_legacy_span(steps, stats, (t0, t1),
-                                               (t1, t2), (t2, t3)))
+                observer.on_chunk(_legacy_span(steps, stats, t_dispatch.t,
+                                               t_fetch.t, t_account.t))
             if flush_flag:
                 flush_flag = jnp.asarray(False)
             pending_zero = stats["pending"] == 0
@@ -1026,7 +1033,8 @@ class DistributedEngine:
             if boundary is not None:
                 # sees the NEXT iteration's flush flag, so a checkpoint
                 # taken here resumes with the correct write-back phase
-                boundary(steps, state, flush_flag, done)
+                with HostSpan("engine.boundary", **at):
+                    boundary(steps, state, flush_flag, done)
             if done:
                 break
             if want_flush:

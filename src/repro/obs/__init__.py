@@ -9,14 +9,14 @@ from .export import to_trace_events, trace_dict, write_trace
 from .imbalance import (cascade_efficacy, gini, imbalance_report,
                         max_over_mean, run_load_matrix, step_metrics,
                         summarize)
-from .metrics import (Counter, Gauge, Histogram, MetricsRegistry,
-                      default_registry)
+from .metrics import Counter, Gauge, MetricsRegistry, default_registry
 from .report import run_report, to_markdown, write_report
-from .timeline import ChunkSpan, Observer, RunMeta, TimelineRecorder
+from .timeline import (ChunkSpan, HostSpan, Observer, RunMeta,
+                       TimelineRecorder)
 
 __all__ = [
-    "Counter", "Gauge", "Histogram", "MetricsRegistry", "default_registry",
-    "ChunkSpan", "Observer", "RunMeta", "TimelineRecorder",
+    "Counter", "Gauge", "MetricsRegistry", "default_registry",
+    "ChunkSpan", "HostSpan", "Observer", "RunMeta", "TimelineRecorder",
     "to_trace_events", "trace_dict", "write_trace",
     "cascade_efficacy", "gini", "imbalance_report", "max_over_mean",
     "run_load_matrix", "step_metrics", "summarize",

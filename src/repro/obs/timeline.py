@@ -20,6 +20,16 @@ per-tile (monolithic, ``tv_*``) or per-chip (distributed, ``pc_*``)
 load vectors per superstep; they ride the same chunk fetch and feed
 ``obs.imbalance``.  The simulated-time BSP spans are derived after the
 run from ``RunResult.trace`` (``obs.export``).
+
+Every host boundary the run loops time is a :class:`HostSpan`, which
+writes the span into an active ``jax.profiler`` trace too, on the device
+trace's clock: ``engine.dispatch`` / ``engine.fetch`` /
+``engine.account`` per chunk (arguments ``chunk`` and ``step``, the
+chunk's index and first superstep), ``engine.boundary`` around the
+fault-tolerance hook, ``engine.run_start`` from ``run()`` entry to the
+first dispatch, ``engine.finish`` after the loop, and
+``engine.init_state``.  Without an active profiler the annotation is a
+no-op.
 """
 from __future__ import annotations
 
@@ -28,6 +38,7 @@ import time
 from typing import Dict, List, Optional, Protocol, runtime_checkable
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 
 @dataclasses.dataclass(frozen=True)
@@ -106,6 +117,28 @@ class Observer(Protocol):
 
 def now() -> float:
     return time.perf_counter()
+
+
+class HostSpan:
+    """``with HostSpan(name, **args) as s:`` — one host span with two
+    sinks: a ``jax.profiler.TraceAnnotation`` named ``name`` with
+    ``args`` as its arguments, and ``s.t``, the ``(start, end)``
+    ``perf_counter`` stamps a :class:`ChunkSpan` stores."""
+
+    __slots__ = ("_note", "t")
+
+    def __init__(self, name: str, **args):
+        self._note = TraceAnnotation(name, **args)
+        self.t = (0.0, 0.0)
+
+    def __enter__(self) -> "HostSpan":
+        self._note.__enter__()
+        self.t = (now(), 0.0)
+        return self
+
+    def __exit__(self, *exc):
+        self.t = (self.t[0], now())
+        return self._note.__exit__(*exc)
 
 
 class TimelineRecorder:

@@ -14,7 +14,10 @@ Acceptance properties:
     positive cascade efficacy vs the no-proxy baseline;
   * the metrics registry is deterministic and survives snapshot/reset.
 """
+import glob
 import json
+import os
+import re
 
 import numpy as np
 import pytest
@@ -26,7 +29,9 @@ from repro.graph.rmat import histogram_input
 from repro.obs import export as obs_export
 from repro.obs import imbalance as obs_imbalance
 from repro.obs import report as obs_report
-from repro.obs.metrics import Histogram, MetricsRegistry, default_registry
+from repro.obs.metrics import MetricsRegistry, default_registry
+
+from _subproc import run_devices
 
 GRID = square_grid(16)
 CHUNK = 8
@@ -126,6 +131,98 @@ def test_legacy_loop_emits_per_step_spans(g, root):
     assert len(rec.spans) == r.run.supersteps
     assert all(s.n_steps == 1 for s in rec.spans)
     assert rec.supersteps == r.run.supersteps
+
+
+# ------------------------------------------- profiler spans and phase scopes
+# the superstep phase scopes the benchmark's trace reduction reads
+# (bench/phases.py), innermost first on an operation's scope path
+PHASES = ("front", "proxy", "delivery", "charge")
+
+
+def _engine_spans(log_dir):
+    """{span name: [chunk argument, ...]} of the ``engine.*`` host spans
+    in the one profiler trace under ``log_dir``."""
+    from jax.profiler import ProfileData
+    (path,) = glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
+                        recursive=True)
+    spans = {}
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("engine."):
+                    spans.setdefault(e.name, []).append(
+                        dict(e.stats).get("chunk"))
+    return spans
+
+
+@pytest.mark.parametrize("chips", [0, 4])
+def test_profiler_trace_holds_engine_spans(g, root, chips, tmp_path):
+    """Under an active profiler every chunk the observer sees is one
+    ``engine.dispatch`` / ``fetch`` / ``account`` span with the chunk's
+    index, and the run stays bit-identical to one with no profiler."""
+    import jax
+    base = _run("bfs", g, root, chips=chips)
+    rec = obs.TimelineRecorder()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        r = _run("bfs", g, root, chips=chips, observer=rec)
+    finally:
+        jax.profiler.stop_trace()
+    assert np.array_equal(base.values, r.values)
+    assert base.run.counters.as_dict() == r.run.counters.as_dict()
+    assert base.run.trace.to_dict() == r.run.trace.to_dict()
+    spans = _engine_spans(str(tmp_path))
+    chunks = [s.index for s in rec.spans]
+    assert len(chunks) > 1
+    for name in ("engine.dispatch", "engine.fetch", "engine.account"):
+        assert sorted(spans[name]) == chunks, name
+    for name in ("engine.init_state", "engine.run_start", "engine.finish"):
+        assert len(spans[name]) == 1, name
+
+
+def _scopes(hlo_text: str) -> set:
+    """Every scope name on every ``op_name`` path in HLO text, with
+    transform wrappers taken off (``vmap(front)`` is ``front``)."""
+    return {part.rsplit("(", 1)[-1].rstrip(")")
+            for path in re.findall(r'op_name="([^"]*)"', hlo_text)
+            for part in path.split("/")}
+
+
+def test_chunk_program_carries_phase_scopes(g, root):
+    import jax.numpy as jnp
+    from repro.core.engine import DataLocalEngine, EngineConfig
+    cfg = EngineConfig(grid=GRID, n_src=g.n_rows, n_dst=g.n_cols, oq_cap=8,
+                       proxy=apps.table2_proxy(GRID, "bfs"))
+    eng = DataLocalEngine(apps.BFS_SPEC, cfg, g.row_lo, g.row_hi,
+                          g.col_idx, g.weights)
+    state = eng.init_state(seed_idx=root, seed_val=0.0)
+    false = jnp.zeros((), jnp.bool_)
+    hlo = eng._chunk.lower(eng.graph, state, false, false, jnp.int32(64),
+                           length=CHUNK).compile().as_text()
+    assert set(PHASES) <= _scopes(hlo)
+
+
+def test_mesh_chunk_program_carries_exchange_scope(tmp_path):
+    """On a 4-device mesh the compiled chunk program (the one that ran,
+    dumped by XLA) also carries the board exchange's scope."""
+    out = run_devices(f"""
+os.environ["XLA_FLAGS"] += " --xla_dump_to={tmp_path}"
+import numpy as np
+from repro.core.tilegrid import square_grid
+from repro.graph import apps, rmat_edges
+g = rmat_edges(7, edge_factor=8, seed=1)
+grid = square_grid(16)
+r = apps.bfs(g, int(np.argmax(g.out_degree())), grid, chips=4,
+             backend="shard_map", proxy=apps.table2_proxy(grid, "bfs"),
+             oq_cap=16, run_chunk=8)
+print("supersteps", r.run.supersteps)
+""", n=4)
+    assert "supersteps" in out
+    scoped = [_scopes(open(p).read()) for p in glob.glob(
+        str(tmp_path / "*after_optimizations.txt"))]
+    chunk = [s for s in scoped if "exchange" in s]
+    assert chunk, "no compiled program carries the exchange scope"
+    assert all(set(PHASES) <= s for s in chunk)
 
 
 # ------------------------------------------------------ trace-event export
@@ -317,30 +414,12 @@ def test_metrics_registry_basics():
     assert c.value == 3.0
     assert reg.counter("a.b") is c
     reg.gauge("g").set(7)
-    h = reg.histogram("h")
-    for v in range(100):
-        h.observe(float(v))
-    assert h.count == 100 and h.min == 0.0 and h.max == 99.0
-    assert h.mean == pytest.approx(49.5)
     snap = reg.snapshot()
     assert snap["counters"]["a.b"] == 3.0
     assert snap["gauges"]["g"] == 7.0
-    assert snap["histograms"]["h"]["count"] == 100
     assert json.dumps(snap)          # JSON-serializable
     reg.reset()
-    assert reg.snapshot() == dict(counters={}, gauges={}, histograms={})
-
-
-def test_histogram_reservoir_deterministic():
-    h1, h2 = Histogram("x", sample_cap=32), Histogram("x", sample_cap=32)
-    for v in range(5000):
-        h1.observe(float(v))
-        h2.observe(float(v))
-    assert h1.summary() == h2.summary()
-    assert h1.percentile(50) == h2.percentile(50)
-    # the systematic sample still spans the stream
-    assert h1.percentile(0) <= h1.percentile(50) <= h1.percentile(100)
-    assert h1.summary()["p95"] > h1.summary()["p50"]
+    assert reg.snapshot() == dict(counters={}, gauges={})
 
 
 def test_progress_reporter_emits_metrics(g, root, capsys):
