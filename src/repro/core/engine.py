@@ -458,20 +458,12 @@ class DataLocalEngine:
         take_v2d = capped - jnp.concatenate(
             [jnp.zeros((T, 1), jnp.int32), capped[:, :-1]], axis=1)
         total_take = capped[:, -1]                            # (T,)
-        b_idx = jnp.arange(B, dtype=jnp.int32)
-        vslot = jax.vmap(
-            functools.partial(jnp.searchsorted, side="right"),
-            in_axes=(0, None))(capped, b_idx)
-        vslot = jnp.minimum(vslot, Cs - 1)                    # (T, B)
-        capped_prev = capped - take_v2d
-        offset = b_idx[None, :] - jnp.take_along_axis(capped_prev, vslot, axis=1)
-        vglob = vslot + jnp.arange(T, dtype=jnp.int32)[:, None] * Cs
-        pos = cur_lo[vglob] + offset
-        emit_mask = b_idx[None, :] < total_take[:, None]
+        pos, lane_val, emit_mask = _emit_lanes(
+            capped, take_v2d, cur_lo.reshape(T, Cs), cur_val.reshape(T, Cs), B)
         col_idx = graph["col_idx"]
         pos = jnp.clip(pos, 0, col_idx.shape[0] - 1)
         dst = col_idx[pos]
-        cand = self._edge_value(graph, cur_val[vglob], pos)
+        cand = self._edge_value(graph, lane_val, pos)
         cur_lo = cur_lo + (take_v2d.reshape(-1))
 
         # flatten records (tile ids are global; dst indices are global)
@@ -556,20 +548,12 @@ class DataLocalEngine:
         take_v2d = capped - jnp.concatenate(
             [jnp.zeros((W, 1), jnp.int32), capped[:, :-1]], axis=1)
         total_take = capped[:, -1]                            # (W,)
-        b_idx = jnp.arange(B, dtype=jnp.int32)
-        vslot = jax.vmap(
-            functools.partial(jnp.searchsorted, side="right"),
-            in_axes=(0, None))(capped, b_idx)
-        vslot = jnp.minimum(vslot, Cs - 1)                    # (W, B)
-        capped_prev = capped - take_v2d
-        offset = b_idx[None, :] - jnp.take_along_axis(capped_prev, vslot, axis=1)
-        vglob = vslot + jnp.arange(W, dtype=jnp.int32)[:, None] * Cs
-        pos = cur_loW[vglob] + offset
-        emit_mask = b_idx[None, :] < total_take[:, None]
+        pos, lane_val, emit_mask = _emit_lanes(
+            capped, take_v2d, cur_loW.reshape(W, Cs), cur_valW.reshape(W, Cs), B)
         col_idx = graph["col_idx"]
         pos = jnp.clip(pos, 0, col_idx.shape[0] - 1)
         dst = col_idx[pos]
-        cand = self._edge_value(graph, cur_valW[vglob], pos)
+        cand = self._edge_value(graph, lane_val, pos)
         cur_loW = cur_loW + (take_v2d.reshape(-1))
 
         # ---- ONE fused (W, .) scatter-back for the whole state ------------
@@ -1792,6 +1776,43 @@ def _deliver_pallas(mail_val, mail_flag, dst, val, mask, owner, T, Nd,
     mf = mail_flag | (cnt > 0)
     per_tile = jnp.sum(cnt.reshape(T, Nd // T), axis=1)
     return mv, mf, per_tile
+
+
+def _emit_lanes(capped, take_v2d, cur_lo2, cur_val2, B: int):
+    """The OQ emit's lane -> source-slot map, shared by both fronts.
+
+    Row t's B emission lanes stream its slots' remaining edges in slot
+    order: ``capped`` (rows, Cs) is the inclusive prefix of the slots'
+    remainders capped at B (non-decreasing along each row), ``take_v2d``
+    each slot's share of it, ``cur_lo2``/``cur_val2`` the (rows, Cs)
+    cursor views.  Lane b reads slot ``s = min(#{c : capped[t, c] <= b},
+    Cs - 1)`` (``searchsorted(capped[t], b, side='right')``, clamped), so
+    a lane past the row's total reads the last slot, masked out.
+
+    No search and no gather: ``c <= s`` holds exactly where the slot
+    before c is spent by lane b (``capped[t, c-1] <= b``), so ``x[t, s]``
+    is the sum of x's steps ``x[t, c] - x[t, c-1]`` over those c — one
+    dense compare over the row's Cs slots per lane, fused with its
+    reductions (no (rows, B, Cs) buffer).  int32 sums wrap exactly, and
+    the value rides as its int32 bits, so every lane equals a searchsorted
+    lookup and gathers bit for bit (inf, NaN and -0.0 included).
+
+    Returns (pos, lane_val, emit_mask), each (rows, B): the lane's edge
+    position (unclipped), its slot's cursor value, and b < row total."""
+    b_idx = jnp.arange(B, dtype=jnp.int32)
+    capped_prev = capped - take_v2d                 # capped[t, c-1]; 0 at c=0
+    upto = capped_prev[:, None, :] <= b_idx[:, None]    # (rows, B, Cs)
+
+    def at_slot(x):
+        steps = jnp.diff(x, axis=1, prepend=0)
+        return jnp.sum(jnp.where(upto, steps[:, None, :], 0), axis=2,
+                       dtype=jnp.int32)
+
+    pos = b_idx + at_slot(cur_lo2 - capped_prev)
+    bits = at_slot(jax.lax.bitcast_convert_type(cur_val2, jnp.int32))
+    lane_val = jax.lax.bitcast_convert_type(bits, jnp.float32)
+    emit_mask = b_idx < capped[:, -1:]
+    return pos, lane_val, emit_mask
 
 
 def capacity_ladder(T: int, levels: int) -> tuple:

@@ -10,6 +10,8 @@ asks for more fast memory than it may use, a program that does not fit.
     2**22-entry mailbox;
   * the dense BFS chunk step (the scanned superstep loop) compiles for
     4096 tiles and 2**22 vertices;
+  * the OQ emit's lane -> slot map compiles to a dense compare with no
+    gather, no loop and no scratch buffer;
   * the chunk step's lowered program does not grow with the edge count:
     the graph is an argument of the program, never a baked-in constant.
 
@@ -125,3 +127,16 @@ def test_chunk_program_size_independent_of_edges(one_chip):
         eng, state, _ = apps.engine_and_state("bfs", g, grid, root=0)
         sizes.append(len(_chunk_lowering(eng, state, one_chip).as_text()))
     assert sizes[0] == sizes[1], sizes
+
+
+def test_emit_lanes_compile_dense(one_chip):
+    """The OQ emit's lane -> slot map at the cells' shapes (4096 tiles,
+    64 slots, oq_cap 64) is one dense compare fused with its reductions:
+    no gather, no search loop, and no (tiles, lanes, slots) buffer."""
+    from repro.core.engine import _emit_lanes
+    ints = _on(one_chip, (TILES, 64), I32)
+    compiled = jax.jit(_emit_lanes, static_argnums=4).lower(
+        ints, ints, ints, _on(one_chip, (TILES, 64), F32), 64).compile()
+    hlo = compiled.as_text()
+    assert " gather(" not in hlo and " while(" not in hlo
+    assert compiled.memory_analysis().temp_size_in_bytes == 0
