@@ -409,9 +409,10 @@ class DataLocalEngine:
 
         Returns (new_vals, mail_val, mail_flag, cur_lo, cur_hi, cur_val,
         consumed_vec, edges_vec, consumed_full, edges_full, dst, cand,
-        emit_mask, src_tile): full-length state arrays, per-lane count
-        vectors (here lane == tile), their (T,) per-tile renderings, and
-        the flattened emission record stream."""
+        emit_mask, src_tile, churn): full-length state arrays, per-lane
+        count vectors (here lane == tile), their (T,) per-tile
+        renderings, the flattened emission record stream, and the
+        superstep's ``(reactivations, stale_edges)`` (see :func:`_churn`)."""
         app, cfg = self.app, self.cfg
         T, Cs, Cd = self.T, self.Cs, self.Cd
         is_min = app.combine == "min"
@@ -440,12 +441,15 @@ class DataLocalEngine:
         consumed_per_tile = jnp.sum(take2d, axis=1)
 
         cur_lo, cur_hi, cur_val = state["cur_lo"], state["cur_hi"], state["cur_val"]
+        churn = _NO_CHURN
         if app.reactivate:
             # an improving record restarts the item's edge cursor with the
             # new value (re-expansion of an already-visited item is the
             # engine's rendering of data staleness: measurable wasted work).
             re = improved[: self.Ns] if self.Nd == self.Ns else jnp.zeros(
                 (self.Ns,), jnp.bool_)
+            churn = _churn(re, vals[: self.Ns], ident, cur_lo,
+                           graph["row_lo"])
             cur_lo = jnp.where(re, graph["row_lo"], cur_lo)
             cur_hi = jnp.where(re, graph["row_hi"], cur_hi)
             cur_val = jnp.where(re, new_vals[: self.Ns], cur_val)
@@ -474,7 +478,7 @@ class DataLocalEngine:
         src_tile = jnp.repeat(tile_gids, B)
         return (new_vals, mail_val, mail_flag, cur_lo, cur_hi, cur_val,
                 consumed_per_tile, total_take, consumed_per_tile,
-                total_take, dst, cand, emit_mask, src_tile)
+                total_take, dst, cand, emit_mask, src_tile, churn)
 
     @jax.named_scope("front")
     def _front_compact(self, graph, state, chip_id, active, W):
@@ -530,11 +534,13 @@ class DataLocalEngine:
         cur_hiW = state["cur_hi"].reshape(T, Cs)[w_rows].reshape(-1)
         cur_valW = state["cur_val"].reshape(T, Cs)[w_rows].reshape(-1)
         react = app.reactivate and self.Nd == self.Ns
+        churn = _NO_CHURN
         if react:
             # Cd == Cs here, so ``improvedW`` is laid out exactly like
             # the windowed cursor rows (the dense path's improved[:Ns])
             row_loW = graph["row_lo"].reshape(T, Cs)[w_rows].reshape(-1)
             row_hiW = graph["row_hi"].reshape(T, Cs)[w_rows].reshape(-1)
+            churn = _churn(improvedW, valsW, ident, cur_loW, row_loW)
             cur_loW = jnp.where(improvedW, row_loW, cur_loW)
             cur_hiW = jnp.where(improvedW, row_hiW, cur_hiW)
             cur_valW = jnp.where(improvedW, nvW, cur_valW)
@@ -605,7 +611,7 @@ class DataLocalEngine:
             consumed_full = edges_full = None
         return (new_vals, mail_val, mail_flag, cur_lo, cur_hi, cur_val,
                 consumedW, total_take, consumed_full, edges_full, dst,
-                cand, emit_mask, src_tile)
+                cand, emit_mask, src_tile, churn)
 
     def _step(self, graph, state, chip_id, flush, active=None,
               window=None, pad_off_to=None):
@@ -619,14 +625,14 @@ class DataLocalEngine:
         if window is None:
             (new_vals, mail_val, mail_flag, cur_lo, cur_hi, cur_val,
              consumed_vec, edges_vec, consumed_per_tile, edges_per_tile,
-             dst, cand, emit_mask, src_tile) = self._front_dense(
+             dst, cand, emit_mask, src_tile, churn) = self._front_dense(
                 graph, state, tile_gids)
         else:
             if active is None:
                 active = self._active_tiles(state)
             (new_vals, mail_val, mail_flag, cur_lo, cur_hi, cur_val,
              consumed_vec, edges_vec, consumed_per_tile, edges_per_tile,
-             dst, cand, emit_mask, src_tile) = self._front_compact(
+             dst, cand, emit_mask, src_tile, churn) = self._front_compact(
                 graph, state, chip_id, active, window)
         vals = state["values"]
         owner = jnp.minimum(dst // Cd, self.Tg - 1)
@@ -636,6 +642,7 @@ class DataLocalEngine:
         # window drops are exact zeros (max over non-negatives, sums)
         stats = dict(edges_processed=jnp.sum(edges_vec),
                      records_consumed=jnp.sum(consumed_vec),
+                     reactivations=churn[0], stale_edges=churn[1],
                      compute_per_tile_max=jnp.max(
                          consumed_vec * PU_OPS_PER_RECORD
                          + edges_vec * PU_OPS_PER_EDGE),
@@ -1279,7 +1286,8 @@ class DataLocalEngine:
                 stats = jax.device_get(stats)
                 sync_ctr.inc()
             steps += 1
-            with HostSpan("engine.account", **at) as t_account:
+            with HostSpan("engine.account", **at,
+                          **_churn_args(stats)) as t_account:
                 account(stats)
             if observer is not None:
                 observer.on_chunk(_legacy_span(steps, stats, t_dispatch.t,
@@ -1342,7 +1350,9 @@ def superstep_counters(stats) -> TrafficCounters:
         off_chip_msgs=stats.get("off_chip_msgs", 0.0),
         off_chip_hop_msgs=stats.get("off_chip_hop_msgs", 0.0),
         edges_processed=stats["edges_processed"],
-        records_consumed=stats["records_consumed"], supersteps=1)
+        records_consumed=stats["records_consumed"],
+        reactivations=stats.get("reactivations", 0.0),
+        stale_edges=stats.get("stale_edges", 0.0), supersteps=1)
 
 
 def superstep_cycles(stats, pkg, links: dict) -> float:
@@ -1393,7 +1403,9 @@ def chunk_counters(stacked, n_active: int) -> TrafficCounters:
         off_chip_msgs=tot("off_chip_msgs"),
         off_chip_hop_msgs=tot("off_chip_hop_msgs"),
         edges_processed=tot("edges_processed"),
-        records_consumed=tot("records_consumed"), supersteps=n)
+        records_consumed=tot("records_consumed"),
+        reactivations=tot("reactivations"), stale_edges=tot("stale_edges"),
+        supersteps=n)
 
 
 def chunk_cycles(stacked, n_active: int, pkg, links: dict) -> np.ndarray:
@@ -1422,8 +1434,18 @@ def chunk_cycles(stacked, n_active: int, pkg, links: dict) -> np.ndarray:
 # packed_step).  "p_resident" joined after the repro.analysis jaxpr
 # linter's int-stat-f32-row rule flagged it: write-back P$ residency is
 # bounded by T*slots, which passes 2**24 at the paper's million-PU scale.
+# "reactivations" and "stale_edges" (cursor restarts, see _churn) are
+# int32 counts that grow like edges_processed.
 _EXACT_INT_STATS = ("pending", "edges_processed", "records_consumed",
-                    "p_resident")
+                    "p_resident", "reactivations", "stale_edges")
+
+
+def _churn_args(stats) -> dict:
+    """The ``engine.account`` span's arguments beyond its place: the
+    chunk's ``reactivations`` and ``stale_edges``, summed over ``stats``'
+    rows (a chunk's masked rows hold 0)."""
+    return {k: int(np.sum(stats[k])) for k in ("reactivations",
+                                                 "stale_edges")}
 
 
 def _stat_keys(step_one, state, flush):
@@ -1487,7 +1509,9 @@ def _drain_chunked(chunk_fn, state, maxs, keys, counters, trace,
             host_done, packed, ints, vecs = jax.device_get(
                 (done, packed, ints, vecs))
             sync_ctr.inc()
-        with HostSpan("engine.account", **at) as t_account:
+        with HostSpan("engine.account", **at,
+                      **_churn_args(dict(zip(_EXACT_INT_STATS, ints.T)))
+                      ) as t_account:
             stacked = {k: packed[:, i] for i, k in enumerate(keys)}
             for i, k in enumerate(_EXACT_INT_STATS):
                 stacked[k] = ints[:, i]      # exact int32, not the f32 row
@@ -1813,6 +1837,26 @@ def _emit_lanes(capped, take_v2d, cur_lo2, cur_val2, B: int):
     lane_val = jax.lax.bitcast_convert_type(bits, jnp.float32)
     emit_mask = b_idx < capped[:, -1:]
     return pos, lane_val, emit_mask
+
+
+# the churn of an app that never restarts a cursor
+_NO_CHURN = (jnp.int32(0), jnp.int32(0))
+
+
+def _churn(restart, vals, ident, cur_lo, row_lo):
+    """``(reactivations, stale_edges)`` of one superstep's cursor
+    restarts, shared by both fronts: ``restart`` marks the items whose
+    cursor an improvement restarts, ``vals`` their values and ``cur_lo``
+    their cursors before it.  A reactivation is a restart of an item
+    already reached (its value was not the combine identity); its stale
+    edges are those it had streamed under the old value, ``cur_lo -
+    row_lo``.  A cursor that never started (0, below ``row_lo``) streamed
+    none.  Exact int32 sums: the dense and compacted fronts give the same
+    counts, since every lane a window drops restarts nothing."""
+    again = restart & (vals != ident)
+    stale = jnp.where(again, jnp.maximum(cur_lo - row_lo, 0), 0)
+    return (jnp.sum(again, dtype=jnp.int32),
+            jnp.sum(stale, dtype=jnp.int32))
 
 
 def capacity_ladder(T: int, levels: int) -> tuple:

@@ -49,6 +49,8 @@ class TrafficCounters:
     dropped_backpressure: float = 0.0
     edges_processed: float = 0.0
     records_consumed: float = 0.0    # mailbox records drained by owners
+    reactivations: float = 0.0       # cursor restarts of reached items
+    stale_edges: float = 0.0         # edges they had streamed before
     supersteps: int = 0
 
     def add(self, other: "TrafficCounters") -> "TrafficCounters":

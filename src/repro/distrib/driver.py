@@ -71,7 +71,8 @@ from ..runtime.elastic import reshard_checkpoint
 from ..runtime.fault import ChipLostError
 from ..runtime.straggler import detect_stragglers, rebalance_chunks
 from ..core.engine import (INF, AppSpec, DataLocalEngine, EngineConfig,
-                           RunResult, _drain_chunked, _legacy_span, _pad,
+                           RunResult, _churn_args, _drain_chunked,
+                           _legacy_span, _pad,
                            _ProgressReporter, _sanitize_gate, _scan_steps,
                            _stat_keys, bucket_index, chunk_cycles,
                            superstep_counters, superstep_cycles)
@@ -1017,7 +1018,8 @@ class DistributedEngine:
                 stats = jax.device_get(stats)
                 sync_ctr.inc()
             steps += 1
-            with HostSpan("engine.account", **at) as t_account:
+            with HostSpan("engine.account", **at,
+                          **_churn_args(stats)) as t_account:
                 account(stats)
             if observer is not None:
                 observer.on_chunk(_legacy_span(steps, stats, t_dispatch.t,

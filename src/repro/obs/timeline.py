@@ -25,9 +25,10 @@ Every host boundary the run loops time is a :class:`HostSpan`, which
 writes the span into an active ``jax.profiler`` trace too, on the device
 trace's clock: ``engine.dispatch`` / ``engine.fetch`` /
 ``engine.account`` per chunk (arguments ``chunk`` and ``step``, the
-chunk's index and first superstep), ``engine.boundary`` around the
-fault-tolerance hook, ``engine.run_start`` from ``run()`` entry to the
-first dispatch, ``engine.finish`` after the loop, and
+chunk's index and first superstep; ``engine.account`` also carries the
+chunk's ``reactivations`` and ``stale_edges``), ``engine.boundary``
+around the fault-tolerance hook, ``engine.run_start`` from ``run()``
+entry to the first dispatch, ``engine.finish`` after the loop, and
 ``engine.init_state``.  Without an active profiler the annotation is a
 no-op.
 """
